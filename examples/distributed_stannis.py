@@ -15,6 +15,10 @@
             genuine bus silence, masks the group out (b_g -> 0), a
             restarted worker rejoins at its benchmark knee — and the
             workers never recompile (CheckpointAck.n_compiles == 1).
+            This process never touches JAX (the plan comes from a fixed
+            curve); on a TPU host the manager gives each training worker
+            a chip of its own, so phase 2 needs two chips and is refused
+            up front on a one-chip host.
 
   PYTHONPATH=src python examples/distributed_stannis.py [--steps 12]
       [--runtime process|local|socket] [--staleness K]

@@ -4,8 +4,10 @@ Dispatch policy (``impl`` argument or ``REPRO_KERNEL_IMPL`` env):
   * ``blocked`` (default) — pure-jnp online-softmax / chunked-scan refs.
     Numerically identical to the Pallas kernels, lowers on any backend and
     under any SPMD sharding; this is what the dry-run and CPU training use.
-  * ``pallas``  — the Pallas TPU kernels (interpret=True off-TPU). On a
-    real TPU fleet this is the production path.
+  * ``pallas``  — the Pallas TPU kernels, in interpret mode on the CPU
+    backend only. A call the kernel cannot serve (a ``kv_mask``, a
+    ``q_offset``, an ``initial_state`` or a ragged chunk) raises rather
+    than quietly running the reference.
   * ``naive``   — O(S^2) einsum oracle (tests only).
 
 Models keep the (B, S, H, D) layout; this module adapts to kernel layouts.
@@ -27,11 +29,15 @@ def _impl(override: Optional[str]) -> str:
     return override or os.environ.get("REPRO_KERNEL_IMPL", "blocked")
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+def _interpret() -> bool:
+    """Interpret mode on the CPU backend, compiled kernels elsewhere."""
+    return jax.default_backend() == "cpu"
+
+
+def _unservable(kernel: str, why: str) -> NotImplementedError:
+    return NotImplementedError(
+        f"impl='pallas': the {kernel} kernel does not support {why}; "
+        f"ask for impl='blocked'")
 
 
 def attention(
@@ -49,13 +55,15 @@ def attention(
 ) -> jnp.ndarray:
     """Multi-head (GQA) attention with causal / sliding-window masking."""
     impl = _impl(impl)
-    if impl == "pallas" and kv_mask is None and q_offset == 0:
+    if impl == "pallas":
+        if kv_mask is not None or q_offset != 0:
+            raise _unservable("flash_attention", "kv_mask or q_offset")
         qt = q.transpose(0, 2, 1, 3)
         kt = k.transpose(0, 2, 1, 3)
         vt = v.transpose(0, 2, 1, 3)
         out = _fa.flash_attention(
             qt, kt, vt, causal=causal, sliding_window=sliding_window,
-            block_q=block_q, block_k=block_k, interpret=not _on_tpu())
+            block_q=block_q, block_k=block_k, interpret=_interpret())
         return out.transpose(0, 2, 1, 3)
     if impl == "naive":
         return _ref.attention_naive(
@@ -114,11 +122,14 @@ def ssd(
     impl = _impl(impl)
     s = x.shape[1]
     chunk = min(chunk, s)
-    if impl == "pallas" and initial_state is None and s % chunk == 0:
+    if impl == "pallas":
+        if initial_state is not None or s % chunk:
+            raise _unservable("ssd_scan", "initial_state or a sequence "
+                              "that is not a multiple of the chunk")
         xt = x.transpose(0, 2, 1, 3)
         dtt = dt.transpose(0, 2, 1)
         y = _ssd.ssd_scan(xt, dtt, A, B_mat, C_mat, D,
-                          chunk=chunk, interpret=not _on_tpu())
+                          chunk=chunk, interpret=_interpret())
         return y.transpose(0, 2, 1, 3), None
     if impl == "naive":
         return _ref.ssd_naive(x, dt, A, B_mat, C_mat, D,

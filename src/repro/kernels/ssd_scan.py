@@ -9,8 +9,10 @@ does the SSD blocked algorithm (arXiv:2405.21060):
   inter:  y_o = (C ⊙ decay_from_start) state
 
 Layouts (ops.py adapts): x (B, H, S, P), dt (B, H, S), B/C (B, S, N),
-A (1, H), D (1, H). Q=chunk (default 256), N≤256, P=64 keep the working
-set (Q*Q + 2*Q*N + Q*P + N*P floats ≈ 0.5 MB) well inside VMEM.
+A (H,), D (H,). dt enters the kernel as (B, H, 1, S), so its block is a
+lane-dense (1, Q) row; A and D are whole f32 vectors in SMEM. Q=chunk
+(default 256), N≤256, P=64 keep the working set (a few (Q, Q) f32
+temporaries + 2*Q*N + Q*P + N*P floats ≈ 1.5 MB) well inside VMEM.
 """
 from __future__ import annotations
 
@@ -24,6 +26,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref,
                 y_ref, state_ref, *, chunk: int):
+    h = pl.program_id(1)
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -31,36 +34,44 @@ def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref,
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[0, 0].astype(jnp.float32)          # (Q, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)        # (Q,)
+    dt_row = dt_ref[0, 0].astype(jnp.float32)    # (1, Q)
     B = b_ref[0].astype(jnp.float32)             # (Q, N)
     C = c_ref[0].astype(jnp.float32)             # (Q, N)
-    A = a_ref[0, 0].astype(jnp.float32)          # scalar for this head
-    D = d_ref[0, 0].astype(jnp.float32)
+    A = a_ref[h]                                 # scalars from SMEM
+    D = d_ref[h]
 
-    a = dt * A                                   # (Q,) log-decays
-    cum = jnp.cumsum(a)                          # inclusive
-    a_tot = cum[-1]
-
-    # intra-chunk
-    seg = cum[:, None] - cum[None, :]            # (Q, Q)
+    # the prefix sums as masked row/column reductions over (Q, Q): Mosaic
+    # has no cumsum, and the column form of dt needs no transpose
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    dt_col = jnp.sum(jnp.where(ii == jj, dt_row, 0.0), axis=1,
+                     keepdims=True)              # (Q, 1)
+    a_row = dt_row * A                           # log-decays
+    a_col = dt_col * A
+    cum_col = jnp.sum(jnp.where(jj <= ii, a_row, 0.0), axis=1,
+                      keepdims=True)             # (Q, 1) inclusive
+    cum_row = jnp.sum(jnp.where(ii <= jj, a_col, 0.0), axis=0,
+                      keepdims=True)             # (1, Q) inclusive
+    a_tot = jnp.sum(a_row, axis=1, keepdims=True)  # (1, 1)
+
+    # intra-chunk
+    seg = cum_col - cum_row                      # (Q, Q)
     L = jnp.where(ii >= jj, jnp.exp(seg), 0.0)
     cb = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)   # (Q,Q)
-    w = cb * L * dt[None, :]
+    w = cb * L * dt_row
     y_d = jax.lax.dot_general(w, x, (((1,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)  # (Q,P)
 
     # inter-chunk (uses state BEFORE this chunk)
     st = state_ref[...]                          # (N, P)
-    dfs = jnp.exp(cum)                           # (Q,)
-    y_o = jax.lax.dot_general(C * dfs[:, None], st, (((1,), (0,)), ((), ())),
+    y_o = jax.lax.dot_general(C * jnp.exp(cum_col), st,
+                              (((1,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)  # (Q,P)
 
     # state update
-    dte = jnp.exp(a_tot - cum) * dt              # (Q,)
-    st_c = jax.lax.dot_general(B * dte[:, None], x, (((0,), (0,)), ((), ())),
+    dte = jnp.exp(a_tot - cum_col) * dt_col      # (Q, 1)
+    st_c = jax.lax.dot_general(B * dte, x, (((0,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)  # (N,P)
     state_ref[...] = st * jnp.exp(a_tot) + st_c
 
@@ -89,15 +100,16 @@ def ssd_scan(
         grid=(b, h, nc),
         in_specs=[
             pl.BlockSpec((1, 1, chunk, p), lambda b_, h_, c: (b_, h_, c, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda b_, h_, c: (b_, h_, c)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda b_, h_, c: (b_, h_, 0, c)),
             pl.BlockSpec((1, chunk, n), lambda b_, h_, c: (b_, c, 0)),
             pl.BlockSpec((1, chunk, n), lambda b_, h_, c: (b_, c, 0)),
-            pl.BlockSpec((1, 1), lambda b_, h_, c: (0, h_)),
-            pl.BlockSpec((1, 1), lambda b_, h_, c: (0, h_)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, 1, chunk, p), lambda b_, h_, c: (b_, h_, c, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-    )(x, dt, B_mat, C_mat, A.reshape(1, h), D.reshape(1, h))
+    )(x, dt.reshape(b, h, 1, s), B_mat, C_mat,
+      A.astype(jnp.float32), D.astype(jnp.float32))
     return y

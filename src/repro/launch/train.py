@@ -19,7 +19,9 @@ Four execution substrates, selected with ``--runtime``:
   process  the Stannis runtime over REAL worker processes, each running
            the jitted train step at its group's live batch size and
            streaming reports back over a pipe. Faults are real: a killed
-           worker produces genuine bus silence;
+           worker produces genuine bus silence. The coordinator never
+           touches JAX (its node probe runs in a child that exits
+           first), and on a TPU host each training worker owns one chip;
   socket   the multi-host mesh backend: the coordinator listens on
            ``--listen host:port`` and workers join over TCP — spawned
            locally by default, or (with ``--external-workers``)
@@ -50,6 +52,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.accel import (check_chip_budget, enable_compile_cache,
+                         host_tpu_chips, one_chip_env, run_with_env)
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs.base import ArchConfig, get_arch, reduced_config
 from repro.core import allocator, hetero_dp
@@ -62,6 +66,10 @@ from repro.models.model_factory import aux_inputs, build_model
 from repro.obs import (LOG, ChromeTraceSink, EventLog, MetricsRegistry,
                        Tracer)
 from repro.optim.optimizer import AdamW, OptConfig
+
+
+# batch sizes the node probe times (paper §III-A)
+PROBE_LADDER = (1, 2, 4, 8)
 
 
 @dataclasses.dataclass
@@ -95,7 +103,11 @@ class HeteroTrainer:
     """The paper's Stannis loop over a real JAX model."""
 
     def __init__(self, arch_cfg: ArchConfig, plan: BatchPlan,
-                 cfg: Optional[TrainerConfig] = None):
+                 cfg: Optional[TrainerConfig] = None,
+                 state: Optional[Tuple] = None):
+        """``state`` is a ``(params, opt_state)`` pair to train from (e.g.
+        a probing trainer's), so only one copy of the model state exists;
+        None initialises both from ``cfg.seed``."""
         self.cfg = cfg or TrainerConfig()
         self.arch_cfg = arch_cfg
         self.plan = plan
@@ -113,8 +125,11 @@ class HeteroTrainer:
             plan, self.cfg.seq_len, arch_cfg.vocab_size,
             seed=self.cfg.seed, private_frac=self.cfg.private_frac)
         self.opt = AdamW(self.cfg.opt)
-        self.params = self.model.init(jax.random.PRNGKey(self.cfg.seed))
-        self.opt_state = self.opt.init(self.params)
+        if state is None:
+            self.params = self.model.init(jax.random.PRNGKey(self.cfg.seed))
+            self.opt_state = self.opt.init(self.params)
+        else:
+            self.params, self.opt_state = state
         self.step_fn = jax.jit(hetero_dp.make_train_step(
             self.model, self.opt, remat=self.cfg.remat))
         self.ckpt = (Checkpointer(self.cfg.ckpt_dir, keep=self.cfg.keep_ckpts)
@@ -128,12 +143,23 @@ class HeteroTrainer:
     @classmethod
     def from_probe(cls, arch_cfg: ArchConfig,
                    groups: Dict[str, Tuple[int, SpeedModel]],
-                   cfg: Optional[TrainerConfig] = None) -> "HeteroTrainer":
+                   cfg: Optional[TrainerConfig] = None,
+                   state: Optional[Tuple] = None) -> "HeteroTrainer":
         cfg = cfg or TrainerConfig()
         plan = allocator.solve(groups, cfg.dataset_size)
-        return cls(arch_cfg, plan, cfg)
+        return cls(arch_cfg, plan, cfg, state)
 
-    def probe_speed_model(self, batch_ladder=(1, 2, 4, 8),
+    @classmethod
+    def for_probe(cls, arch_cfg: ArchConfig,
+                  cfg: Optional[TrainerConfig] = None) -> "HeteroTrainer":
+        """A trainer that exists to run :meth:`probe_speed_model` before
+        the real groups are known (its one-group plan is a placeholder)."""
+        boot_plan = allocator.solve(
+            {"probe": (1, SpeedModel(np.array([1.0, 2, 4]),
+                                     np.array([1.0, 2, 4])))}, 64)
+        return cls(arch_cfg, boot_plan, cfg)
+
+    def probe_speed_model(self, batch_ladder=PROBE_LADDER,
                           iters: int = 2) -> SpeedModel:
         """Benchmark THIS node (paper §III-A): time real jitted steps at a
         ladder of batch sizes. On a fleet every node class runs this."""
@@ -407,6 +433,12 @@ def events_report_fn(interferences, dropouts) -> Optional[Callable]:
     return fn
 
 
+def _train_in_workers(args) -> bool:
+    return (args.worker_train == "on"
+            or (args.worker_train == "auto"
+                and args.runtime in ("process", "socket")))
+
+
 def _run_distributed(args, cfg: TrainerConfig, sm: SpeedModel,
                      interferences, dropouts) -> None:
     """Drive training through the Stannis runtime (repro.runtime): a
@@ -435,9 +467,7 @@ def _run_distributed(args, cfg: TrainerConfig, sm: SpeedModel,
                  f"{args.runtime} runtime does not persist checkpoints yet",
                  runtime=args.runtime)
     plan = allocator.solve(_parse_groups(args.groups, sm), cfg.dataset_size)
-    train_workers = (args.worker_train == "on"
-                     or (args.worker_train == "auto"
-                         and args.runtime in ("process", "socket")))
+    train_workers = _train_in_workers(args)
     train = ({"arch": args.arch, "seq_len": args.seq_len,
               "reduced": not args.full_size} if train_workers else None)
     cp = ControlPlane(plan, [policy_from_config(cfg.hypertune)],
@@ -680,6 +710,7 @@ def main() -> None:
             argv += ["--round-timeout", str(args.round_timeout)]
         raise SystemExit(search_main(argv))
 
+    enable_compile_cache()
     arch = get_arch(args.arch)
     if not args.full_size:
         arch = reduced_config(arch)
@@ -688,28 +719,28 @@ def main() -> None:
                         ckpt_every=10 if args.ckpt_dir else 0)
     interferences, dropouts = parse_interfere(args.interfere)
 
-    # probe this node once, reuse the curve for every group (single-host
-    # stand-in; a fleet probes per node class)
-    boot_plan = allocator.solve(
-        {"probe": (1, SpeedModel(np.array([1.0, 2, 4]),
-                                 np.array([1.0, 2, 4])))}, 64)
-    bootstrap = HeteroTrainer(arch, boot_plan, cfg)
-    sm = bootstrap.probe_speed_model()
-    LOG.info("probe", f"probe: knee={sm.knee()} vmax={sm.vmax:.2f} samp/s",
-             knee=float(sm.knee()), vmax=float(sm.vmax))
-
     if args.runtime != "inproc":
+        train_workers = _train_in_workers(args)
+        if train_workers and args.runtime in ("process", "socket"):
+            # one process per chip: refuse a run whose training workers
+            # cannot each own one, and keep the coordinator off JAX —
+            # the probe runs in a child that exits before workers spawn
+            if not args.external_workers:
+                try:
+                    check_chip_budget(len(args.groups.split(",")),
+                                      host_tpu_chips())
+                except ValueError as e:
+                    ap.error(str(e))
+            sm = probe_in_child(arch, cfg)
+        else:
+            sm = HeteroTrainer.for_probe(arch, cfg).probe_speed_model()
+        _log_probe(sm)
         _run_distributed(args, cfg, sm, interferences, dropouts)
         return
 
-    trainer = HeteroTrainer.from_probe(arch, _parse_groups(args.groups, sm),
-                                       cfg)
-    trainer.params = bootstrap.params        # reuse init
-    if args.resume:
-        if trainer.resume():
-            LOG.info("resume", f"resumed at step {trainer.step}",
-                     step=trainer.step)
-    recs = trainer.run(report_fn=events_report_fn(interferences, dropouts))
+    trainer = train_inproc(arch, cfg, args.groups, interferences, dropouts,
+                           resume=args.resume)
+    recs = trainer.records
     retunes = [r for r in recs if r.retune]
     LOG.info("inproc_done",
              f"done: {len(recs)} steps, {len(retunes)} retunes, "
@@ -717,6 +748,65 @@ def main() -> None:
              steps=len(recs), retunes=len(retunes), loss=recs[-1].loss)
     for r in retunes:
         LOG.info("retune", f"  retune @ step {r.step}: {r.retune}")
+
+
+def _log_probe(sm: SpeedModel) -> None:
+    LOG.info("probe", f"probe: knee={sm.knee()} vmax={sm.vmax:.2f} samp/s",
+             knee=float(sm.knee()), vmax=float(sm.vmax))
+
+
+def train_inproc(arch: ArchConfig, cfg: TrainerConfig, groups: str,
+                 interferences=(), dropouts=(), *, resume: bool = False,
+                 batch_ladder=PROBE_LADDER) -> HeteroTrainer:
+    """The inproc main path: probe this node, allocate the groups with
+    Eq. 1 from that one curve (single-host stand-in; a fleet probes per
+    node class), then train ``cfg.steps`` capacity-masked steps under the
+    ``--interfere`` schedule. Returns the trainer (``records`` holds the
+    steps). The probing trainer's state becomes the training state, so
+    one copy of the model state exists."""
+    bootstrap = HeteroTrainer.for_probe(arch, cfg)
+    sm = bootstrap.probe_speed_model(batch_ladder)
+    _log_probe(sm)
+    trainer = HeteroTrainer.from_probe(
+        arch, _parse_groups(groups, sm), cfg,
+        state=(bootstrap.params, bootstrap.opt_state))
+    del bootstrap
+    if resume and trainer.resume():
+        LOG.info("resume", f"resumed at step {trainer.step}",
+                 step=trainer.step)
+    trainer.run(report_fn=events_report_fn(interferences, dropouts))
+    return trainer
+
+
+def _probe_child(arch: ArchConfig, cfg: TrainerConfig, conn) -> None:
+    enable_compile_cache()
+    sm = HeteroTrainer.for_probe(arch, cfg).probe_speed_model()
+    conn.send((sm.batch_sizes.tolist(), sm.speeds.tolist()))
+    conn.close()
+
+
+def probe_in_child(arch: ArchConfig, cfg: TrainerConfig) -> SpeedModel:
+    """Probe this node in a spawn-context child (on chip 0 of a TPU
+    host) that exits before the caller starts training workers, so the
+    caller never holds a chip."""
+    import multiprocessing
+
+    ctx = multiprocessing.get_context("spawn")
+    recv, send = ctx.Pipe(duplex=False)
+    env = one_chip_env(0) if host_tpu_chips() else {}
+    proc = ctx.Process(target=run_with_env,
+                       args=(env, _probe_child, arch, cfg, send),
+                       name="stannis-probe")
+    proc.start()
+    send.close()
+    try:
+        batches, speeds = recv.recv()
+    except EOFError:
+        proc.join()
+        raise RuntimeError(f"node probe process failed (exit code "
+                           f"{proc.exitcode})") from None
+    proc.join()
+    return SpeedModel(np.asarray(batches), np.asarray(speeds))
 
 
 if __name__ == "__main__":

@@ -99,6 +99,8 @@ def connect_and_serve(endpoint: str, group: str, incarnation: int = 0,
     while True:
         done = _serve_once(endpoint, group, incarnation, retry_for,
                            hello_timeout, replay, rng)
+        if done.status == "failed":
+            raise SystemExit(f"worker {group}: {done.error}")
         if done.status == "shutdown" or not resume:
             return
         incarnation += 1

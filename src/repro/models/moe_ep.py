@@ -33,16 +33,6 @@ from repro.configs.base import ArchConfig
 from repro.models import shardings as sh
 
 
-def _shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-    """shard_map across JAX API flavors: jax.shard_map(check_vma=...) on
-    new releases, jax.experimental.shard_map(check_rep=...) on 0.4.x."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma)
-
 Params = dict
 
 
@@ -129,7 +119,7 @@ def moe_block_fs(p: Params, cfg: ArchConfig, x: jnp.ndarray
             aux = jax.lax.pmean(aux, axis_name=ax)
         return y.astype(dt), aux
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec, None, None), P(None, None),
                   P(None, None, "model"), P(None, None, "model"),
@@ -257,7 +247,7 @@ def moe_block_ep(p: Params, cfg: ArchConfig, x: jnp.ndarray
         return y.reshape(bl, sl, e).astype(dt), aux
 
     spec_x = P(bspec, "model", None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec_x, P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),

@@ -14,7 +14,8 @@ that execution substrate (DESIGN.md §10):
 """
 from repro.runtime.eventloop import (EventLoop, FaultAction,
                                      RetuneLagTracker, RoundStats,
-                                     RuntimeResult, specs_from_plan)
+                                     RuntimeResult, WorkerFailed,
+                                     specs_from_plan)
 from repro.runtime.managers import (MANAGERS, ExecutionManager, LocalManager,
                                     ProcessManager, SocketExecutionManager)
 from repro.runtime.messages import (CheckpointAck, CheckpointRequest, Goodbye,
@@ -25,7 +26,7 @@ from repro.runtime.worker import (InterferenceSpec, SpeedGovernor,
 
 __all__ = [
     "EventLoop", "FaultAction", "RetuneLagTracker", "RoundStats",
-    "RuntimeResult", "specs_from_plan",
+    "RuntimeResult", "WorkerFailed", "specs_from_plan",
     "MANAGERS", "ExecutionManager", "LocalManager", "ProcessManager",
     "SocketExecutionManager",
     "CheckpointAck", "CheckpointRequest", "Goodbye", "Hello", "Message",

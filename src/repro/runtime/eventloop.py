@@ -67,6 +67,11 @@ from repro.runtime.messages import (CheckpointAck, CheckpointRequest, Goodbye,
 from repro.runtime.worker import InterferenceSpec, WorkerSpec
 
 
+class WorkerFailed(RuntimeError):
+    """A worker could not serve at all (e.g. its training executor
+    failed to build). Unlike a dropout, this ends the run."""
+
+
 @dataclasses.dataclass
 class FaultAction:
     """One scheduled fault-injection action. ``action`` is one of
@@ -745,6 +750,8 @@ class EventLoop:
                     self._ack_deadlines.pop(msg.step, None)
         elif isinstance(msg, Goodbye):
             self.manager.mark_dead(name)
+            if msg.error:
+                raise WorkerFailed(f"worker {name!r} failed: {msg.error}")
         elif isinstance(msg, Hello):
             pass                         # late duplicate; handshake owns it
 

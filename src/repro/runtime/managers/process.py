@@ -22,6 +22,7 @@ import multiprocessing
 import os
 import signal
 
+from repro.accel import ChipSlots, run_with_env
 from repro.runtime.ipc.pipe import PipeChannel
 from repro.runtime.ipc.shm import shm_available
 from repro.runtime.managers.base import ExecutionManager, WorkerHandle
@@ -33,9 +34,30 @@ class SpawnedProcessFaults:
     processes (``self._procs``: {group: Process}) — the SIGKILL + join,
     SIGSTOP/SIGCONT, and join-then-force-stop teardown semantics live
     here ONCE, for both the pipe (ProcessManager) and socket
-    (SocketExecutionManager) transports."""
+    (SocketExecutionManager) transports.
+
+    Chips, too: on a TPU host each spawned training worker is given one
+    chip of its own (``self._chips``), and a start that asks for more
+    training workers than the host has chips is refused before anything
+    spawns."""
 
     _procs: dict
+    _chips: ChipSlots
+    _spawn: bool = True
+
+    def start(self, specs) -> None:
+        specs = list(specs)
+        if self._spawn:
+            self._chips.check(specs)
+        super().start(specs)
+
+    def _start_proc(self, spec: WorkerSpec, target, args, name: str):
+        proc = self._ctx.Process(
+            target=run_with_env, args=(self._chips.env(spec), target, *args),
+            name=name, daemon=True)
+        proc.start()
+        self._procs[spec.group] = proc
+        return proc
 
     def _kill_proc(self, group: str) -> None:
         proc = self._procs.get(group)
@@ -69,6 +91,7 @@ class ProcessManager(SpawnedProcessFaults, ExecutionManager):
         super().__init__(hello_timeout, chaos=chaos)
         self._ctx = multiprocessing.get_context("spawn")
         self._procs = {}
+        self._chips = ChipSlots()
 
     def _launch(self, spec: WorkerSpec) -> WorkerHandle:
         if shm_available():
@@ -77,12 +100,9 @@ class ProcessManager(SpawnedProcessFaults, ExecutionManager):
             # shared-memory ring, not the pipe (DESIGN.md §13)
             spec.bulk = "shm"
         coord_conn, worker_conn = self._ctx.Pipe()
-        proc = self._ctx.Process(target=worker_entry,
-                                 args=(spec.to_wire(), worker_conn),
-                                 name=f"stannis-{spec.group}", daemon=True)
-        proc.start()
+        self._start_proc(spec, worker_entry, (spec.to_wire(), worker_conn),
+                         f"stannis-{spec.group}")
         worker_conn.close()                      # child's end only
-        self._procs[spec.group] = proc
         return WorkerHandle(spec, PipeChannel(coord_conn))
 
     def kill(self, group: str) -> None:

@@ -46,6 +46,7 @@ import socket as _socket
 import time
 from typing import Dict, List, Optional, Tuple
 
+from repro.accel import ChipSlots
 from repro.runtime.ipc import ChannelClosed
 from repro.runtime.ipc.codec import negotiate
 from repro.runtime.ipc.socket import SocketChannel, parse_endpoint
@@ -95,6 +96,7 @@ class SocketExecutionManager(SpawnedProcessFaults, ExecutionManager):
         self.codec = codec
         self._ctx = multiprocessing.get_context("spawn")
         self._procs: Dict[str, "multiprocessing.Process"] = {}
+        self._chips = ChipSlots()
         # connections whose join-Hello named a group we are not (yet)
         # launching: kept until their spec's _launch claims them
         self._parked: Dict[str, Tuple[SocketChannel, Hello]] = {}
@@ -104,12 +106,9 @@ class SocketExecutionManager(SpawnedProcessFaults, ExecutionManager):
         if self._spawn:
             from repro.launch.worker import connect_and_serve
 
-            proc = self._ctx.Process(
-                target=connect_and_serve,
-                args=(self.advertised, spec.group, spec.incarnation),
-                name=f"stannis-sock-{spec.group}", daemon=True)
-            proc.start()
-            self._procs[spec.group] = proc
+            self._start_proc(spec, connect_and_serve,
+                             (self.advertised, spec.group, spec.incarnation),
+                             f"stannis-sock-{spec.group}")
         chan, join = self._accept_group(spec.group)
         # same-host workers (spawned, or a standalone that reports our
         # hostname) may ship bulk payloads through the shared-memory

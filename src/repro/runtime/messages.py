@@ -22,7 +22,8 @@ The protocol (one synchronous round):
   worker     -> coordinator   Goodbye        best-effort, before exit
 
 A killed or suspended worker simply stops producing ``StepReportMsg`` —
-there is no failure message type. Liveness is *derived* from that
+there is no failure message type (only a worker that cannot serve at
+all says why, in its ``Goodbye``). Liveness is *derived* from that
 silence by the control plane, exactly as on the simulator's bus.
 
 Wire shape: ``to_wire`` yields ``(kind, {field: value})`` built from a
@@ -347,12 +348,19 @@ class Shutdown(Message):
 @register
 @dataclasses.dataclass
 class Goodbye(Message):
+    """Best-effort farewell. ``error`` is set when the worker could not
+    serve at all (its training executor failed to build): the
+    coordinator fails the run instead of reading the exit as a dropout.
+    Omitted from the wire while empty (legacy shape)."""
+
     kind: ClassVar[str] = "goodbye"
     wire_id: ClassVar[int] = 9
-    wire_optional: ClassVar[frozenset] = frozenset({"seq"})
+    wire_optional: ClassVar[frozenset] = frozenset({"seq", "error"})
+    wire_tail: ClassVar[frozenset] = frozenset({"seq", "error"})
     group: str
     worker_step: int
     seq: int = -1
+    error: str = ""
 
 
 @register

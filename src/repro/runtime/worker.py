@@ -34,6 +34,7 @@ import json
 import os
 import socket as _socket
 import time
+import traceback
 from typing import Deque, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -41,7 +42,7 @@ import numpy as np
 from repro.core.interference import (govern_speed, window_capacity,
                                      window_speed_cap)
 from repro.core.speed_model import SpeedModel
-from repro.obs import NULL_TRACER, Tracer
+from repro.obs import LOG, NULL_TRACER, Tracer
 from repro.runtime.ipc import (Channel, ChannelClosed, CorruptFrame,
                                DEFAULT_RESYNC_BUDGET, ReliableChannel)
 from repro.runtime.ipc.shm import (BulkUnavailable, ShmBulkPlane,
@@ -162,23 +163,30 @@ class SpeedGovernor:
 
 
 class TrainExecutor:
-    """Real training substrate: a reduced-config model + jitted
-    ``make_train_step``, run at the group's live batch size inside its
-    capacity-row mask. Built lazily so report-only workers never import
-    jax."""
+    """Real training substrate: a model + jitted ``make_train_step``, run
+    at the group's live batch size inside its capacity-row mask. Built
+    lazily so report-only workers never import jax.
+
+    ``spec.train``: ``arch`` (registered name), ``seq_len``, ``reduced``
+    (default True: the tiny CPU config) and optional ``overrides``, a
+    dict of :class:`ArchConfig` fields replaced after that (e.g. a depth
+    or vocabulary cut of a full-width config)."""
 
     def __init__(self, spec: WorkerSpec) -> None:
         import jax
         import jax.numpy as jnp
 
+        from repro.accel import enable_compile_cache
         from repro.configs.base import get_arch, reduced_config
         from repro.core import hetero_dp
         from repro.models.model_factory import aux_inputs, build_model
         from repro.optim.optimizer import AdamW, OptConfig
 
+        enable_compile_cache()
         cfg = get_arch(spec.train["arch"])
         if spec.train.get("reduced", True):
             cfg = reduced_config(cfg)
+        cfg = dataclasses.replace(cfg, **spec.train.get("overrides", {}))
         self.seq_len = int(spec.train.get("seq_len", 32))
         self.capacity = max(spec.capacity, 1)
         self.model = build_model(cfg)
@@ -197,6 +205,19 @@ class TrainExecutor:
                                       jnp.float32, concrete=True))
         self._jnp = jnp
         self._jax = jax
+        self.losses: Deque[List[float]] = collections.deque(
+            maxlen=_SPEED_HISTORY)       # [batch_size, loss] per step
+        dev = jax.devices()[0]
+        self.device = {"platform": dev.platform, "kind": dev.device_kind,
+                       "id": dev.id,
+                       "coords": list(getattr(dev, "coords", None) or []),
+                       "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS",
+                                                       ""),
+                       "count": len(jax.devices())}
+        LOG.info("worker_device",
+                 f"worker {spec.group}: {dev.platform} device {dev.id} "
+                 f"({dev.device_kind}) of {len(jax.devices())} visible",
+                 group=spec.group, **self.device)
 
     def run_step(self, batch_size: int) -> Tuple[float, float]:
         """One jitted step with the first ``batch_size`` capacity rows
@@ -209,7 +230,16 @@ class TrainExecutor:
         self.params, self.opt_state, metrics = self.step_fn(
             self.params, self.opt_state, batch)
         loss = float(metrics["loss"])            # blocks
+        self.losses.append([batch_size, loss])
         return loss, max(time.perf_counter() - t0, 1e-9)
+
+    def summary(self) -> Dict:
+        """What the checkpoint state blob reports of this executor: the
+        device it trains on and its (batch size, loss) history."""
+        peak = (self._jax.devices()[0].memory_stats() or {}).get(
+            "peak_bytes_in_use")
+        return {"device": dict(self.device, peak_bytes_in_use=peak),
+                "losses": list(self.losses)}
 
     @property
     def n_compiles(self) -> int:
@@ -220,8 +250,10 @@ class TrainExecutor:
 class WorkerExit:
     """Why :func:`run_worker` returned, and what it could not deliver.
 
-    ``status`` is ``"shutdown"`` (orderly, coordinator said so) or
-    ``"closed"`` (the channel died under the worker). ``carry`` is the
+    ``status`` is ``"shutdown"`` (orderly, coordinator said so),
+    ``"closed"`` (the channel died under the worker) or ``"failed"``
+    (the training executor could not be built; ``error`` says why, and
+    the coordinator was told in a ``Goodbye``). ``carry`` is the
     undelivered backlog — unflushed pending reports plus, on a session
     channel, every frame the coordinator never acked — which a
     self-healing socket worker replays through its NEXT incarnation's
@@ -229,6 +261,7 @@ class WorkerExit:
 
     status: str
     carry: List[Message] = dataclasses.field(default_factory=list)
+    error: str = ""
 
 
 def run_worker(spec: WorkerSpec, chan: Channel,
@@ -277,6 +310,7 @@ def run_worker(spec: WorkerSpec, chan: Channel,
         pending.clear()
 
     exit_status = "closed"
+    error = ""
     try:
         chan.put(Hello(spec.group, os.getpid(), spec.batch_size,
                        spec.incarnation, host=_socket.gethostname()))
@@ -301,7 +335,20 @@ def run_worker(spec: WorkerSpec, chan: Channel,
             if isinstance(msg, StepGrant):        # hot path first
                 if executor is None and spec.train:
                     with tr.span("worker", "train_init"):
-                        executor = TrainExecutor(spec)
+                        try:
+                            executor = TrainExecutor(spec)
+                        except Exception as e:
+                            # a worker that cannot train must fail the
+                            # run, not read as a dropout: say why, exit
+                            error = f"{type(e).__name__}: {e}"
+                            LOG.warn("worker_init_failed",
+                                     f"worker {spec.group}: "
+                                     f"{traceback.format_exc()}",
+                                     group=spec.group, error=error)
+                            chan.put(Goodbye(spec.group, worker_step,
+                                             error=error))
+                            exit_status = "failed"
+                            break
                 t0 = tr.now() if tr else 0.0
                 report = _one_step(spec, gov, sm, executor, msg.step,
                                    speed_memo)
@@ -344,13 +391,17 @@ def run_worker(spec: WorkerSpec, chan: Channel,
                         bulk_plane = ShmBulkPlane()
                     except (BulkUnavailable, OSError):
                         spec.bulk = "inline"     # degrade, don't retry
-                state = json.dumps({
+                state = {
                     "group": spec.group,
                     "worker_step": worker_step,
                     "batch_size": spec.batch_size,
                     "n_compiles": executor.n_compiles if executor else 0,
                     "speed_history": list(speed_history),
-                }, separators=(",", ":")).encode("utf-8")
+                }
+                if executor is not None:
+                    state.update(executor.summary())
+                state = json.dumps(state, separators=(",", ":")).encode(
+                    "utf-8")
                 ack = CheckpointAck(
                     msg.step, spec.group, worker_step, spec.batch_size,
                     executor.n_compiles if executor else 0,
@@ -371,7 +422,7 @@ def run_worker(spec: WorkerSpec, chan: Channel,
             carry.extend(m for m in chan.unacked_messages()
                          if not isinstance(m, Goodbye))
         chan.close()
-    return WorkerExit(exit_status, carry)
+    return WorkerExit(exit_status, carry, error)
 
 
 def _one_step(spec: WorkerSpec, gov: SpeedGovernor, sm: SpeedModel,
@@ -434,4 +485,7 @@ def worker_entry(spec_wire: Dict, connection) -> None:
     primitives and wrap the inherited Connection."""
     from repro.runtime.ipc.pipe import PipeChannel
 
-    run_worker(WorkerSpec.from_wire(spec_wire), PipeChannel(connection))
+    done = run_worker(WorkerSpec.from_wire(spec_wire),
+                      PipeChannel(connection))
+    if done.status == "failed":
+        raise SystemExit(f"worker: {done.error}")

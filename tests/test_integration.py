@@ -12,7 +12,9 @@ from repro.core.allocator import solve
 from repro.core.speed_model import SpeedModel
 from repro.launch.serve import Server
 from repro.launch.train import (HeteroTrainer, TrainerConfig,
-                                dropout_report_fn, interference_report_fn)
+                                dropout_report_fn, interference_report_fn,
+                                parse_interfere, probe_in_child,
+                                train_inproc)
 
 
 def tiny_cfg(arch="deepseek-7b", **kw):
@@ -155,6 +157,37 @@ class TestProbe:
         sm = t.probe_speed_model(batch_ladder=(1, 4, 8), iters=1)
         assert sm.vmax > 0
         assert sm.speed(8) >= sm.speed(1) * 0.5   # timing noise tolerated
+
+    def test_probe_in_child_returns_the_curve(self):
+        """The coordinator-side probe of a process run: a spawned child
+        times the ladder and exits; the caller gets only the curve."""
+        sm = probe_in_child(tiny_cfg(), trainer_cfg(seq_len=8))
+        assert list(sm.batch_sizes) == [1, 2, 4, 8]
+        assert sm.vmax > 0
+
+
+class TestTrainInproc:
+    """The inproc main path shared by the train CLI and chip_smoke.py."""
+
+    def test_probe_allocate_train_retune_without_recompile(self):
+        ivs, drops = parse_interfere("worker@2x0.4")
+        t = train_inproc(tiny_cfg(), trainer_cfg(steps=10), "host:1,worker:2",
+                         ivs, drops, batch_ladder=(1, 2, 4, 8))
+        assert len(t.records) == 10
+        assert all(np.isfinite(r.loss) for r in t.records)
+        assert [r for r in t.records if r.retune], "no retune"
+        assert t.step_fn._cache_size() == 1
+        assert set(t.plan.batch_sizes()) == {"host", "worker"}
+
+    def test_given_state_is_trained_not_copied(self):
+        """One copy of the model state: a trainer built with the probing
+        trainer's (params, opt_state) holds those very arrays."""
+        boot = HeteroTrainer.for_probe(tiny_cfg(), trainer_cfg())
+        t = HeteroTrainer(tiny_cfg(), small_plan(), trainer_cfg(),
+                          state=(boot.params, boot.opt_state))
+        assert all(a is b for a, b in zip(jax.tree.leaves(t.params),
+                                          jax.tree.leaves(boot.params)))
+        assert t.opt_state is boot.opt_state
 
 
 class TestServe:

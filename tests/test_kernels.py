@@ -70,6 +70,31 @@ class TestFlashAttentionSweep:
             np.testing.assert_allclose(outs[0], o, rtol=1e-5, atol=1e-5)
 
 
+class TestPallasRefusals:
+    """impl='pallas' never quietly runs the reference: a call the kernel
+    cannot serve raises."""
+
+    def test_attention_with_kv_mask(self):
+        q, k, v = make_qkv(1, 32, 32, 2, 2, 16, jnp.float32)
+        with pytest.raises(NotImplementedError, match="blocked"):
+            ops.attention(q, k, v, kv_mask=jnp.ones((1, 32), bool),
+                          impl="pallas")
+
+    def test_attention_with_q_offset(self):
+        q, k, v = make_qkv(1, 1, 32, 2, 2, 16, jnp.float32)
+        with pytest.raises(NotImplementedError, match="blocked"):
+            ops.attention(q, k, v, q_offset=31, impl="pallas")
+
+    @pytest.mark.parametrize("s,chunk,carry", [(96, 64, False),
+                                               (64, 16, True)])
+    def test_ssd_ragged_chunk_or_initial_state(self, s, chunk, carry):
+        x, dt, A, B, C, D = TestSSDSweep().make(1, s, 2, 8, 8)
+        init = jnp.zeros((1, 2, 8, 8)) if carry else None
+        with pytest.raises(NotImplementedError, match="blocked"):
+            ops.ssd(x, dt, A, B, C, D, chunk=chunk, initial_state=init,
+                    impl="pallas")
+
+
 class TestBlockedAttention:
     """The jnp online-softmax path (dry-run / CPU production path)."""
 

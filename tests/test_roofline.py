@@ -103,10 +103,7 @@ class TestZero1Specs:
     def test_moments_gain_data_axis(self):
         from repro.launch import specs as sp
         from repro.optim.optimizer import OptState
-        try:                                   # jax>=0.5 (sizes, names)
-            mesh = AbstractMesh((16, 16), ("data", "model"))
-        except TypeError:                      # jax 0.4.x shape tuple
-            mesh = AbstractMesh((("data", 16), ("model", 16)))
+        mesh = AbstractMesh((16, 16), ("data", "model"))
 
         params = {"layers": {"wq": jax.ShapeDtypeStruct((32, 4096, 4096),
                                                         jnp.float32)}}

@@ -18,8 +18,8 @@ import pytest
 from repro.core.allocator import solve
 from repro.core.control import ControlPlane, SpeedDeclinePolicy
 from repro.core.speed_model import SpeedModel
-from repro.runtime import (EventLoop, FaultAction, ProcessManager,
-                           specs_from_plan)
+from repro.runtime import (MANAGERS, EventLoop, FaultAction, ProcessManager,
+                           WorkerFailed, specs_from_plan)
 from repro.runtime.parity import dropout_parity, fig6_parity
 
 
@@ -105,3 +105,27 @@ class TestProcessRealTraining:
         assert acks and all(a.n_compiles == 1 for a in acks.values())
         # worker "a" trained every round; "b" lost its first life's steps
         assert acks["a"].worker_step >= 11
+
+
+class TestWorkerInitFailure:
+    @pytest.mark.parametrize("runtime", ["local", "process"])
+    def test_executor_failure_fails_the_run(self, runtime):
+        """A worker whose TrainExecutor cannot be built says why in its
+        Goodbye and the run fails — it is not read as a dropout that the
+        liveness rule masks out while the run "completes"."""
+        sm = SpeedModel(np.array([1.0, 2, 4]), np.array([10.0, 18, 28]))
+        plan = solve({"a": (1, sm)}, 4096)
+        cp = ControlPlane(plan, [SpeedDeclinePolicy()], liveness_timeout=3)
+        specs = specs_from_plan(plan, train={"arch": "no-such-arch",
+                                             "seq_len": 8})
+        manager = MANAGERS[runtime]()
+        loop = EventLoop(cp, manager, round_timeout=60.0)
+        try:
+            manager.start(specs)
+            with pytest.raises(WorkerFailed, match="no-such-arch"):
+                loop.run(4)
+        finally:
+            loop.shutdown()
+        assert not manager.workers["a"].alive
+        if runtime == "process":
+            assert manager._procs["a"].exitcode not in (0, None)

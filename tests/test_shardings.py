@@ -14,17 +14,8 @@ from repro.configs.base import get_arch
 from repro.models import shardings as sh
 
 
-def _abstract_mesh(sizes, names):
-    """AbstractMesh across JAX API flavors: 0.4.x takes a single
-    ((name, size), ...) shape tuple; 0.5+ takes (sizes, names)."""
-    try:
-        return AbstractMesh(tuple(sizes), tuple(names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
-MESH = _abstract_mesh((16, 16), ("data", "model"))
-POD_MESH = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+POD_MESH = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 class TestAdaptSpec:
@@ -141,8 +132,6 @@ mesh2 = Mesh(devs.reshape(2, 2, 2), ("pod", "data", "model"))
 compiled2 = dryrun._lower_compile(cfg, shape, mesh2, moe_ep=False,
                                   remat=True)
 ca = compiled2.cost_analysis()
-if isinstance(ca, (list, tuple)):      # jax<=0.4.x returns [dict]
-    ca = ca[0]
 assert ca.get("flops", 0) > 0
 
 # decode step shards too
