@@ -24,6 +24,9 @@ from repro.models.model_factory import Model
 
 NEG_INF = -1e30
 
+# the jitted train step's name: its HLO module is ``jit_<STEP_NAME>``
+STEP_NAME = "hypertune_train_step"
+
 
 def cross_entropy(logits: jnp.ndarray, targets: jnp.ndarray,
                   vocab_size: int) -> jnp.ndarray:
@@ -66,8 +69,9 @@ def chunked_ce_sums(model: Model, params, hidden: jnp.ndarray,
         h = jax.lax.dynamic_slice_in_dim(hidden, i * chunk, chunk, 1)
         t = jax.lax.dynamic_slice_in_dim(targets, i * chunk, chunk, 1)
         m = jax.lax.dynamic_slice_in_dim(tok_mask, i * chunk, chunk, 1)
-        lg = L.logits(params["embed"], cfg, h)
-        ce = cross_entropy(lg, t, cfg.vocab_size)
+        with jax.named_scope("loss_head"):
+            lg = L.logits(params["embed"], cfg, h)
+            ce = cross_entropy(lg, t, cfg.vocab_size)
         return (tot + (ce * m).sum(), cnt + m.sum()), None
 
     from repro.models.scan_util import layer_scan
@@ -85,14 +89,15 @@ def loss_sums(model: Model, params, batch: Dict[str, Any],
     accumulation can normalize by the GLOBAL token count exactly."""
     seq = batch["tokens"].shape[1]
     m = token_mask(batch, seq)
+    hidden, aux = model.forward(params, batch, remat=remat,
+                                return_hidden=True)
     if ce_chunk:
-        hidden, aux = model.forward(params, batch, remat=remat,
-                                    return_hidden=True)
         tot, cnt = chunked_ce_sums(model, params, hidden, batch["targets"],
                                    m, ce_chunk)
         return tot, cnt, aux
-    logits, aux = model.forward(params, batch, remat=remat)
-    ce = cross_entropy(logits, batch["targets"], model.cfg.vocab_size)
+    with jax.named_scope("loss_head"):
+        logits = L.logits(params["embed"], model.cfg, hidden)
+        ce = cross_entropy(logits, batch["targets"], model.cfg.vocab_size)
     return (ce * m).sum(), m.sum(), aux
 
 
@@ -126,13 +131,13 @@ def make_train_step(model: Model, optimizer, remat=True,
             # narrow the cross-replica gradient all-reduce (§Perf lever);
             # the optimizer re-widens to f32 internally
             grads = jax.tree.map(lambda g: g.astype(grad_dtype), grads)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = jax.tree.map(lambda p, u: p + u, params, updates)
+        params, opt_state = _apply_update(optimizer, grads, opt_state,
+                                          params)
         gn = optimizer.last_grad_norm(opt_state)
         return params, opt_state, {"loss": loss, "grad_norm": gn, **metrics}
 
     if micro_batches <= 1:
-        return single_step
+        return _named(single_step)
 
     def accum_step(params, opt_state, batch):
         m = micro_batches
@@ -169,14 +174,28 @@ def make_train_step(model: Model, optimizer, remat=True,
         grads = jax.tree.map(lambda g, p: g.astype(p.dtype), grads, params)
         ce = tots.sum() / t_global
         aux = auxs.mean()
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = jax.tree.map(lambda p, u: p + u, params, updates)
+        params, opt_state = _apply_update(optimizer, grads, opt_state,
+                                          params)
         gn = optimizer.last_grad_norm(opt_state)
         return params, opt_state, {"loss": ce + aux, "grad_norm": gn,
                                    "ce": ce, "aux": aux,
                                    "tokens": cnts.sum()}
 
-    return accum_step
+    return _named(accum_step)
+
+
+def _apply_update(optimizer, grads, opt_state, params):
+    """The optimizer's update and its addition to the parameters, under
+    the ``optimizer`` name scope."""
+    with jax.named_scope("optimizer"):
+        updates, opt_state = optimizer.update(grads, opt_state, params)
+        params = jax.tree.map(lambda p, u: p + u, params, updates)
+    return params, opt_state
+
+
+def _named(step: Callable) -> Callable:
+    step.__name__ = step.__qualname__ = STEP_NAME
+    return step
 
 
 def make_eval_step(model: Model, remat: bool = False) -> Callable:

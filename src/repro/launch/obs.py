@@ -1,7 +1,7 @@
 """Trace-file tooling: ``python -m repro.launch.obs <cmd> TRACE``.
 
-Reads the run timeline a ``--trace`` run wrote (Chrome trace-event JSON
-or a JSONL sink file — auto-detected) and either
+Reads the run timeline a ``--trace`` run wrote (Chrome trace-event JSON)
+and either
 
   * ``summarize`` — span latency quantiles (p50/p99) per span kind and
     lane, every retune decision with its structured policy rationale,
@@ -132,7 +132,7 @@ def main(argv=None) -> int:
     sub = ap.add_subparsers(dest="cmd", required=True)
     for name in ("summarize", "validate"):
         p = sub.add_parser(name)
-        p.add_argument("trace", help="trace file (Chrome JSON or JSONL)")
+        p.add_argument("trace", help="trace file (Chrome trace-event JSON)")
     args = ap.parse_args(argv)
     return summarize(args.trace) if args.cmd == "summarize" \
         else validate(args.trace)

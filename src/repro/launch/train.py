@@ -43,6 +43,7 @@ CLI:
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import re
 import time
@@ -51,6 +52,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import monitoring
 
 from repro.accel import (check_chip_budget, enable_compile_cache,
                          host_tpu_chips, one_chip_env, run_with_env)
@@ -63,13 +65,17 @@ from repro.core.control import (ControlPlane, HyperTuneConfig, StepReport,
 from repro.core.speed_model import SpeedModel, probe
 from repro.data.pipeline import HeteroPipeline
 from repro.models.model_factory import aux_inputs, build_model
-from repro.obs import (LOG, ChromeTraceSink, EventLog, MetricsRegistry,
-                       Tracer)
+from repro.obs import (LOG, NULL_TRACER, ChromeTraceSink, EventLog,
+                       MetricsRegistry, Tracer, gc_spans)
 from repro.optim.optimizer import AdamW, OptConfig
 
 
 # batch sizes the node probe times (paper §III-A)
 PROBE_LADDER = (1, 2, 4, 8)
+
+# the spans every step of HeteroTrainer.run opens, in order
+STEP_SPANS = ("input", "h2d", "dispatch", "sync", "report", "control")
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
 
 @dataclasses.dataclass
@@ -107,8 +113,13 @@ class HeteroTrainer:
                  state: Optional[Tuple] = None):
         """``state`` is a ``(params, opt_state)`` pair to train from (e.g.
         a probing trainer's), so only one copy of the model state exists;
-        None initialises both from ``cfg.seed``."""
+        None initialises both from ``cfg.seed``.
+
+        ``tracer`` (default ``NULL_TRACER``: off) and ``metrics`` (default
+        None: off) may be set at any time; :meth:`run` reads them."""
         self.cfg = cfg or TrainerConfig()
+        self.tracer = NULL_TRACER
+        self.metrics: Optional[MetricsRegistry] = None
         self.arch_cfg = arch_cfg
         self.plan = plan
         self.model = build_model(arch_cfg)
@@ -238,46 +249,83 @@ class HeteroTrainer:
         """report_fn(step, plan, measured_step_time) -> per-group reports.
         Defaults to healthy reports derived from the plan (each group at
         its required speed); tests/examples wrap it to inject interference
-        or dropouts (returning no entry for a dead group)."""
+        or dropouts (returning no entry for a dead group).
+
+        With ``self.tracer`` set, every step is a run of ``train`` spans
+        (``STEP_SPANS``, then ``retune``/``checkpoint``/``log`` when
+        they happen), garbage collections are ``train/gc`` spans and
+        backend compiles ``train/compile`` instants. With ``self.metrics``
+        set, the ``train.*`` counters count rows, retunes and compiles."""
         steps = steps if steps is not None else self.cfg.steps
         target = self.step + steps
-        while self.step < target:
-            plan = self.control_plane.plan
+        with contextlib.ExitStack() as observing:
+            if self.tracer:
+                observing.enter_context(gc_spans(self.tracer, "train"))
+            if self.tracer or self.metrics is not None:
+                listener = self._on_compile
+                monitoring.register_event_duration_secs_listener(listener)
+                observing.callback(
+                    monitoring.unregister_event_duration_listener, listener)
+            while self.step < target:
+                self._step(report_fn, on_retune)
+            if self.ckpt:
+                with self.tracer.span("train", "checkpoint"):
+                    self.save()
+                    self.ckpt.wait()
+        return self.records
+
+    def _step(self, report_fn, on_retune) -> None:
+        tr, mx = self.tracer, self.metrics
+        plan = self.control_plane.plan
+        with tr.span("train", "input"):
             np_batch = self.pipeline.next_batch()
+        with tr.span("train", "h2d"):
             batch = {
                 "tokens": jnp.asarray(np_batch["tokens"]),
                 "targets": jnp.asarray(np_batch["targets"]),
                 "sample_mask": jnp.asarray(np_batch["sample_mask"]),
             }
-            batch.update(self._aux)
-            t0 = time.perf_counter()
-            self.params, self.opt_state, metrics = self.step_fn(
+        batch.update(self._aux)
+        t0 = time.perf_counter()
+        with tr.span("train", "dispatch"):
+            self.params, self.opt_state, out = self.step_fn(
                 self.params, self.opt_state, batch)
-            loss = float(metrics["loss"])          # blocks
-            dt = max(time.perf_counter() - t0, 1e-9)
+        with tr.span("train", "sync"):
+            loss = float(out["loss"])              # blocks
+        dt = max(time.perf_counter() - t0, 1e-9)
 
+        with tr.span("train", "report"):
             reports = (report_fn(self.step, plan, dt) if report_fn
                        else self._healthy_reports(plan))
             for gname, r in reports.items():
                 self.control_plane.bus.publish(
                     StepReport.from_legacy(self.step, gname, r))
+        with tr.span("train", "control"):
             # one control round: rejoin -> policies -> liveness
             event = self.control_plane.poll(self.step)
-            if event is not None:
+        if event is not None:
+            with tr.span("train", "retune"):
                 self.pipeline.set_plan(self.control_plane.plan)
                 if on_retune:
                     on_retune(event)
+        if mx is not None:
+            mx.counter("train.rows.live").inc(plan.global_batch)
+            mx.counter("train.rows.capacity").inc(plan.global_capacity)
+            if event is not None:
+                mx.counter("train.retunes").inc()
 
-            rec = StepRecord(
-                self.step, loss, plan.global_batch, dt,
-                plan.global_batch / dt,
-                retune=None if event is None else
-                f"{event.group}:{event.old_batch}->{event.new_batch}")
-            self.records.append(rec)
-            self.step += 1
-            if self.cfg.ckpt_every and self.step % self.cfg.ckpt_every == 0:
+        rec = StepRecord(
+            self.step, loss, plan.global_batch, dt,
+            plan.global_batch / dt,
+            retune=None if event is None else
+            f"{event.group}:{event.old_batch}->{event.new_batch}")
+        self.records.append(rec)
+        self.step += 1
+        if self.cfg.ckpt_every and self.step % self.cfg.ckpt_every == 0:
+            with tr.span("train", "checkpoint"):
                 self.save()
-            if self.cfg.log_every and self.step % self.cfg.log_every == 0:
+        if self.cfg.log_every and self.step % self.cfg.log_every == 0:
+            with tr.span("train", "log"):
                 LOG.info("train_step",
                          f"step {self.step:5d} loss {loss:.4f} "
                          f"gb {plan.global_batch} "
@@ -285,10 +333,16 @@ class HeteroTrainer:
                          step=self.step, loss=loss,
                          global_batch=plan.global_batch,
                          throughput=rec.throughput)
-        if self.ckpt:
-            self.save()
-            self.ckpt.wait()
-        return self.records
+
+    def _on_compile(self, event: str, seconds: float, **_) -> None:
+        """JAX's monitoring hook, registered while :meth:`run` observes."""
+        if event != _BACKEND_COMPILE:
+            return
+        tr, mx = self.tracer, self.metrics
+        if tr:
+            tr.instant("train", "compile", {"seconds": seconds})
+        if mx is not None:
+            mx.counter("train.compiles").inc()
 
     @staticmethod
     def _healthy_reports(plan: BatchPlan) -> Dict[str, Dict[str, float]]:
@@ -619,7 +673,8 @@ def main() -> None:
                     help="run real jitted steps inside runtime workers "
                          "(auto: on for --runtime process)")
     ap.add_argument("--trace", default=None, metavar="PATH",
-                    help="write the run timeline (coordinator + worker "
+                    help="write the run timeline (inproc: the trainer's "
+                         "per-step spans; runtimes: coordinator + worker "
                          "spans, retune rationale) as Chrome trace-event "
                          "JSON — open in https://ui.perfetto.dev or "
                          "summarize with python -m repro.launch.obs")
@@ -664,9 +719,9 @@ def main() -> None:
                  "process")
     if args.staleness < 0:
         ap.error("--staleness must be >= 0")
-    if args.runtime == "inproc" and (args.trace or args.metrics_every):
-        ap.error("--trace/--metrics-every instrument the runtime "
-                 "coordinator; use --runtime local, process or socket")
+    if args.runtime == "inproc" and args.metrics_every:
+        ap.error("--metrics-every instruments the runtime coordinator; "
+                 "use --runtime local, process or socket")
     if args.metrics_every < 0:
         ap.error("--metrics-every must be >= 0")
     if args.runtime != "socket":
@@ -738,8 +793,14 @@ def main() -> None:
         _run_distributed(args, cfg, sm, interferences, dropouts)
         return
 
-    trainer = train_inproc(arch, cfg, args.groups, interferences, dropouts,
-                           resume=args.resume)
+    tracer = (Tracer(source="train", sinks=[ChromeTraceSink(args.trace)],
+                     annotate=jax.profiler.TraceAnnotation)
+              if args.trace else NULL_TRACER)
+    try:
+        trainer = train_inproc(arch, cfg, args.groups, interferences,
+                               dropouts, resume=args.resume, tracer=tracer)
+    finally:
+        tracer.close()
     recs = trainer.records
     retunes = [r for r in recs if r.retune]
     LOG.info("inproc_done",
@@ -748,6 +809,10 @@ def main() -> None:
              steps=len(recs), retunes=len(retunes), loss=recs[-1].loss)
     for r in retunes:
         LOG.info("retune", f"  retune @ step {r.step}: {r.retune}")
+    if args.trace:
+        LOG.info("trace_written",
+                 f"trace written to {args.trace} — summarize with: "
+                 f"python -m repro.launch.obs summarize {args.trace}")
 
 
 def _log_probe(sm: SpeedModel) -> None:
@@ -757,13 +822,14 @@ def _log_probe(sm: SpeedModel) -> None:
 
 def train_inproc(arch: ArchConfig, cfg: TrainerConfig, groups: str,
                  interferences=(), dropouts=(), *, resume: bool = False,
-                 batch_ladder=PROBE_LADDER) -> HeteroTrainer:
+                 batch_ladder=PROBE_LADDER,
+                 tracer=NULL_TRACER) -> HeteroTrainer:
     """The inproc main path: probe this node, allocate the groups with
     Eq. 1 from that one curve (single-host stand-in; a fleet probes per
     node class), then train ``cfg.steps`` capacity-masked steps under the
-    ``--interfere`` schedule. Returns the trainer (``records`` holds the
-    steps). The probing trainer's state becomes the training state, so
-    one copy of the model state exists."""
+    ``--interfere`` schedule, traced by ``tracer``. Returns the trainer
+    (``records`` holds the steps). The probing trainer's state becomes
+    the training state, so one copy of the model state exists."""
     bootstrap = HeteroTrainer.for_probe(arch, cfg)
     sm = bootstrap.probe_speed_model(batch_ladder)
     _log_probe(sm)
@@ -774,6 +840,7 @@ def train_inproc(arch: ArchConfig, cfg: TrainerConfig, groups: str,
     if resume and trainer.resume():
         LOG.info("resume", f"resumed at step {trainer.step}",
                  step=trainer.step)
+    trainer.tracer = tracer
     trainer.run(report_fn=events_report_fn(interferences, dropouts))
     return trainer
 

@@ -162,26 +162,28 @@ def attention_block(
     cross_x: Optional[jnp.ndarray] = None,     # (B, Sk, E) for cross-attn
     kv_mask: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
-    """Full-sequence attention (train / prefill)."""
-    kv_src = cross_x if cross_x is not None else x
-    q, k, v = _project_qkv(p, cfg, x, kv_src)
-    if use_rope and cross_x is None:
-        if positions is None:
-            positions = jnp.arange(x.shape[1])
-        cos, sin = rope_tables(positions, cfg.resolved_head_dim, cfg.rope_theta)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-    q = sh.constrain_act(q, "heads")
-    k = sh.constrain_act(k, "heads")
-    v = sh.constrain_act(v, "heads")
-    out = ops.attention(
-        q, k, v, causal=causal and cross_x is None,
-        sliding_window=cfg.sliding_window if cross_x is None else 0,
-        kv_mask=kv_mask)
-    out = named(out, "attn_out")
-    b, s = x.shape[:2]
-    out = out.reshape(b, s, -1) @ p["wo"].astype(x.dtype)
-    return sh.constrain_act(out, "res")
+    """Full-sequence attention (train / prefill), under the ``attention``
+    name scope."""
+    with jax.named_scope("attention"):
+        kv_src = cross_x if cross_x is not None else x
+        q, k, v = _project_qkv(p, cfg, x, kv_src)
+        if use_rope and cross_x is None:
+            if positions is None:
+                positions = jnp.arange(x.shape[1])
+            cos, sin = rope_tables(positions, cfg.resolved_head_dim, cfg.rope_theta)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+        q = sh.constrain_act(q, "heads")
+        k = sh.constrain_act(k, "heads")
+        v = sh.constrain_act(v, "heads")
+        out = ops.attention(
+            q, k, v, causal=causal and cross_x is None,
+            sliding_window=cfg.sliding_window if cross_x is None else 0,
+            kv_mask=kv_mask)
+        out = named(out, "attn_out")
+        b, s = x.shape[:2]
+        out = out.reshape(b, s, -1) @ p["wo"].astype(x.dtype)
+        return sh.constrain_act(out, "res")
 
 
 def attention_decode(
@@ -267,15 +269,17 @@ def init_mlp(key, cfg: ArchConfig, d_ff: Optional[int] = None,
 
 
 def mlp_block(p: Params, cfg: ArchConfig, x: jnp.ndarray) -> jnp.ndarray:
-    dt = x.dtype
-    up = x @ p["w_up"].astype(dt)
-    if "w_gate" in p:
-        h = jax.nn.silu(x @ p["w_gate"].astype(dt)) * up
-    else:
-        h = jax.nn.gelu(up)
-    h = sh.constrain_act(h, "ff")
-    out = h @ p["w_down"].astype(dt)
-    return named(sh.constrain_act(out, "res"), "ffn_out")
+    """The feed-forward block, under the ``mlp`` name scope."""
+    with jax.named_scope("mlp"):
+        dt = x.dtype
+        up = x @ p["w_up"].astype(dt)
+        if "w_gate" in p:
+            h = jax.nn.silu(x @ p["w_gate"].astype(dt)) * up
+        else:
+            h = jax.nn.gelu(up)
+        h = sh.constrain_act(h, "ff")
+        out = h @ p["w_down"].astype(dt)
+        return named(sh.constrain_act(out, "res"), "ffn_out")
 
 
 # ---------------------------------------------------------------------------

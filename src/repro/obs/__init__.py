@@ -3,9 +3,10 @@
 Zero-dependency structured tracing + metrics for the Stannis stack:
 
   trace.py    ``Tracer`` — monotonic-clock spans/instants into a bounded
-              ring buffer with pluggable sinks (JSONL, in-memory, Chrome
+              ring buffer with pluggable sinks (in-memory, Chrome
               trace-event / Perfetto), plus the falsy ``NULL_TRACER``
-              that makes every instrumentation site free when disabled;
+              that makes every instrumentation site free when disabled,
+              and ``gc_spans`` (garbage-collection pauses as spans);
   metrics.py  ``MetricsRegistry`` — counters, gauges and log-bucketed
               histograms (round latency, grant->report lag, frame/byte
               counts, shm hits, fault events);
@@ -18,14 +19,14 @@ the stdlib — workers on any host can carry it.
 """
 from repro.obs.log import LOG, EventLog
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
-from repro.obs.trace import (NULL_TRACER, ChromeTraceSink, JsonlSink,
-                             MemorySink, NullTracer, TraceEvent, Tracer,
-                             chrome_trace, load_trace, validate_events)
+from repro.obs.trace import (NULL_TRACER, ChromeTraceSink, MemorySink,
+                             NullTracer, TraceEvent, Tracer, chrome_trace,
+                             gc_spans, load_trace, validate_events)
 
 __all__ = [
     "LOG", "EventLog",
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "NULL_TRACER", "ChromeTraceSink", "JsonlSink", "MemorySink",
-    "NullTracer", "TraceEvent", "Tracer", "chrome_trace", "load_trace",
+    "NULL_TRACER", "ChromeTraceSink", "MemorySink", "NullTracer",
+    "TraceEvent", "Tracer", "chrome_trace", "gc_spans", "load_trace",
     "validate_events",
 ]

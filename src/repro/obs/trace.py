@@ -26,13 +26,15 @@ zero allocations, zero calls. Sink exceptions are isolated (recorded on
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
+import gc
 import json
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, ContextManager, Dict, Iterator, List, Optional
 
 __all__ = ["TraceEvent", "Tracer", "NullTracer", "NULL_TRACER",
-           "MemorySink", "JsonlSink", "ChromeTraceSink", "chrome_trace",
+           "MemorySink", "ChromeTraceSink", "chrome_trace", "gc_spans",
            "load_trace", "validate_events"]
 
 _PHASES = ("X", "i", "M")                # complete, instant, metadata
@@ -75,9 +77,11 @@ class TraceEvent:
 
 class _Span:
     """Context manager emitting ONE complete event at close (or an
-    ``aborted`` one when unwinding through an exception)."""
+    ``aborted`` one when unwinding through an exception). With the
+    tracer's ``annotate`` set, the span is also an annotation named
+    ``<cat>.<name>`` for as long as it is open."""
 
-    __slots__ = ("_tr", "cat", "name", "args", "t0")
+    __slots__ = ("_tr", "cat", "name", "args", "t0", "_ann")
 
     def __init__(self, tracer: "Tracer", cat: str, name: str,
                  args: Optional[Dict]) -> None:
@@ -86,18 +90,26 @@ class _Span:
         self.name = name
         self.args = args
         self.t0 = 0.0
+        self._ann = None
 
     def __enter__(self) -> "_Span":
+        annotate = self._tr.annotate
+        if annotate is not None:
+            self._ann = annotate(f"{self.cat}.{self.name}")
+            self._ann.__enter__()
         self.t0 = self._tr.now()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        dur = self._tr.now() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         args = self.args
         if exc_type is not None:
             args = dict(args or ())
             args["aborted"] = True
-        self._tr.complete(self.cat, self.name, self.t0,
-                          self._tr.now() - self.t0, args)
+        self._tr.complete(self.cat, self.name, self.t0, dur, args)
         return False
 
 
@@ -107,14 +119,22 @@ class Tracer:
     ``capacity`` bounds the in-memory ring (``events()`` /
     ``drain_wire()`` read it); sinks see EVERY event regardless — the
     ring bounds memory, not the file. Worker-side tracers run ring-only
-    (no sinks) and are drained by the piggyback path."""
+    (no sinks) and are drained by the piggyback path.
+
+    ``annotate`` is a context-manager factory taking a name, entered
+    around every span: ``jax.profiler.TraceAnnotation`` puts each span
+    into a profiler trace as well, on the clock its device operations
+    are aligned to."""
 
     enabled = True
 
     def __init__(self, source: str = "coord", capacity: int = 65536,
                  sinks: Optional[List] = None,
-                 clock: Callable[[], float] = time.perf_counter) -> None:
+                 clock: Callable[[], float] = time.perf_counter,
+                 annotate: Optional[Callable[[str], ContextManager]] = None
+                 ) -> None:
         self.source = source
+        self.annotate = annotate
         self._clock = clock
         self._ring: collections.deque = collections.deque(maxlen=capacity)
         self._sinks = list(sinks or ())
@@ -272,24 +292,6 @@ class MemorySink:
         self.closed = True
 
 
-class JsonlSink:
-    """One JSON object per line, written line-buffered as events arrive
-    — the crash-safe sink: whatever reached the file before a fault is
-    complete, parseable lines."""
-
-    def __init__(self, path: str) -> None:
-        self.path = path
-        self._f = open(path, "w", buffering=1)
-
-    def emit(self, ev: TraceEvent) -> None:
-        self._f.write(json.dumps(ev.to_json(), separators=(",", ":"))
-                      + "\n")
-
-    def close(self) -> None:
-        if not self._f.closed:
-            self._f.close()
-
-
 class ChromeTraceSink:
     """Accumulate events and write one Chrome trace-event JSON object
     (``{"traceEvents": [...]}``) at close — loadable in Perfetto
@@ -337,46 +339,57 @@ def chrome_trace(events: List[TraceEvent]) -> Dict:
     return {"traceEvents": meta + out, "displayTimeUnit": "ms"}
 
 
+@contextlib.contextmanager
+def gc_spans(tracer: "Tracer", cat: str) -> Iterator[None]:
+    """Inside the block, every Python garbage collection becomes a
+    ``<cat>/gc`` span (args: generation, objects collected)."""
+    open_spans: List[_Span] = []
+
+    def hook(phase: str, info: Dict) -> None:
+        if phase == "start":
+            span = tracer.span(cat, "gc", {"generation": info["generation"]})
+            span.__enter__()
+            open_spans.append(span)
+        elif open_spans:
+            span = open_spans.pop()
+            span.args["collected"] = info["collected"]
+            span.__exit__(None, None, None)
+
+    gc.callbacks.append(hook)
+    try:
+        yield
+    finally:
+        gc.callbacks.remove(hook)
+
+
 # -- trace-file readers (the summarize/validate CLI and CI smoke) -----------
 
 
 def load_trace(path: str) -> List[Dict]:
-    """Read a trace file — Chrome JSON (``{"traceEvents": [...]}``) or
-    a JSONL sink file — into a list of event dicts with ``ts``/``dur``
-    normalized to SECONDS and ``src`` resolved to the lane name."""
+    """Read a Chrome trace-event file (``{"traceEvents": [...]}``, what
+    :class:`ChromeTraceSink` writes) into a list of event dicts with
+    ``ts``/``dur`` normalized to SECONDS and ``src`` resolved to the lane
+    name. Raises ValueError for anything else."""
     with open(path) as f:
-        text = f.read()
-    doc = None
-    try:
-        doc = json.loads(text)           # one document = the Chrome file
-    except ValueError:
-        pass                             # many lines = the JSONL sink
-    if isinstance(doc, dict) and "traceEvents" in doc:
-        raw = doc["traceEvents"]
-        names = {ev.get("tid"): (ev.get("args") or {}).get("name")
-                 for ev in raw if ev.get("ph") == "M"
-                 and ev.get("name") == "thread_name"}
-        out = []
-        for ev in raw:
-            if ev.get("ph") == "M":
-                continue
-            out.append({
-                "ts": float(ev.get("ts", 0.0)) / 1e6,
-                "dur": float(ev.get("dur", 0.0)) / 1e6,
-                "cat": ev.get("cat", ""), "name": ev.get("name", ""),
-                "ph": ev.get("ph", "i"),
-                "src": names.get(ev.get("tid"),
-                                 str(ev.get("tid", "?"))),
-                "args": ev.get("args"),
-            })
-        return out
+        doc = json.load(f)
+    if not (isinstance(doc, dict) and "traceEvents" in doc):
+        raise ValueError(f"{path}: not a Chrome trace-event file")
+    raw = doc["traceEvents"]
+    names = {ev.get("tid"): (ev.get("args") or {}).get("name")
+             for ev in raw if ev.get("ph") == "M"
+             and ev.get("name") == "thread_name"}
     out = []
-    for line in text.splitlines():
-        line = line.strip()
-        if line:
-            ev = json.loads(line)
-            ev.setdefault("dur", 0.0)
-            out.append(ev)
+    for ev in raw:
+        if ev.get("ph") == "M":
+            continue
+        out.append({
+            "ts": float(ev.get("ts", 0.0)) / 1e6,
+            "dur": float(ev.get("dur", 0.0)) / 1e6,
+            "cat": ev.get("cat", ""), "name": ev.get("name", ""),
+            "ph": ev.get("ph", "i"),
+            "src": names.get(ev.get("tid"), str(ev.get("tid", "?"))),
+            "args": ev.get("args"),
+        })
     return out
 
 

@@ -26,8 +26,8 @@ import pytest
 
 from repro.core.control.telemetry import StepBuckets, StepReport, TelemetryBus
 from repro.obs import (NULL_TRACER, ChromeTraceSink, Counter, Gauge,
-                       Histogram, JsonlSink, MemorySink, MetricsRegistry,
-                       NullTracer, TraceEvent, Tracer, chrome_trace,
+                       Histogram, MemorySink, MetricsRegistry, NullTracer,
+                       TraceEvent, Tracer, chrome_trace, gc_spans,
                        load_trace, validate_events)
 from repro.runtime.ipc import CODECS
 from repro.runtime.messages import (CheckpointAck, Message, ReportBatch,
@@ -91,6 +91,45 @@ class TestTracer:
         NULL_TRACER.instant("a", "b")
         assert NULL_TRACER.drain_wire() == []
         assert NULL_TRACER.events() == []
+
+    def test_annotate_opens_around_each_span(self):
+        log = []
+
+        class Ann:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                log.append(("in", self.name))
+
+            def __exit__(self, *exc):
+                log.append(("out", self.name, exc[0]))
+
+        tr = Tracer(annotate=Ann)
+        with tr.span("train", "dispatch"):
+            assert log == [("in", "train.dispatch")]
+        with pytest.raises(RuntimeError):
+            with tr.span("train", "sync"):
+                raise RuntimeError("boom")
+        tr.instant("train", "compile")           # instants annotate nothing
+        assert log[1:] == [("out", "train.dispatch", None),
+                           ("in", "train.sync"),
+                           ("out", "train.sync", RuntimeError)]
+        assert [e.name for e in tr.events()] == ["dispatch", "sync",
+                                                 "compile"]
+
+    def test_gc_spans_only_inside_the_block(self):
+        import gc
+
+        tr = Tracer()
+        with gc_spans(tr, "train"):
+            gc.collect()
+        inside = len(tr.events())
+        gc.collect()
+        assert len(tr.events()) == inside >= 1
+        ev = tr.events()[-1]
+        assert (ev.ph, ev.cat, ev.name) == ("X", "train", "gc")
+        assert ev.args["generation"] == 2 and ev.args["collected"] >= 0
 
     def test_drain_wire_pops_the_ring(self):
         tr = Tracer(source="xeon0")
@@ -187,7 +226,7 @@ class TestMetrics:
 
 
 # ---------------------------------------------------------------------------
-# trace files: Chrome export, JSONL, validation
+# trace files: Chrome export, validation
 # ---------------------------------------------------------------------------
 
 
@@ -222,16 +261,15 @@ class TestTraceFiles:
         span = next(e for e in events if e["name"] == "r")
         assert 0.0 <= span["dur"] < 1.0
 
-    def test_jsonl_sink_roundtrip(self, tmp_path):
-        path = str(tmp_path / "trace.jsonl")
-        tr = Tracer(sinks=[JsonlSink(path)])
-        tr.instant("t", "a")
-        with tr.span("t", "b"):
-            pass
-        tr.close()
-        events = load_trace(path)
-        assert [e["name"] for e in events] == ["a", "b"]
-        assert validate_events(events) == []
+    def test_load_trace_refuses_other_files(self, tmp_path):
+        lines = tmp_path / "trace.jsonl"
+        lines.write_text('{"ts": 0, "name": "a", "ph": "i"}\n'
+                         '{"ts": 1, "name": "b", "ph": "i"}\n')
+        other = tmp_path / "other.json"
+        other.write_text('{"events": []}')
+        for path in (lines, other):
+            with pytest.raises(ValueError):
+                load_trace(str(path))
 
     def test_validate_events_catches_malformed(self):
         assert validate_events([]) == ["trace contains no events"]
