@@ -1,9 +1,19 @@
 """Public kernel entry points used by the models.
 
 Dispatch policy (``impl`` argument or ``REPRO_KERNEL_IMPL`` env):
-  * ``blocked`` (default) — pure-jnp online-softmax / chunked-scan refs.
+  * unset (the default) — ``attention`` serves a call with the fused
+    splash kernel (``jax.experimental.pallas.ops.tpu.splash_attention``:
+    forward, dq and dk/dv kernels, grouped KV heads, fully masked causal
+    blocks skipped) when everything the call shows allows it: the TPU
+    backend, self-attention, no ``kv_mask``, ``q_offset`` 0, no sliding
+    window, ``Sq == Sk`` a multiple of 128, a head_dim that is a multiple
+    of 128, and no multi-device model mesh. Every other call, and
+    ``ssd``, takes ``blocked``. ``observe_attention`` reports each choice.
+  * ``blocked`` — pure-jnp online-softmax / chunked-scan refs.
     Numerically identical to the Pallas kernels, lowers on any backend and
     under any SPMD sharding; this is what the dry-run and CPU training use.
+  * ``splash``  — the fused splash kernel for every ``attention`` call, in
+    interpret mode on the CPU backend; a call it cannot serve raises.
   * ``pallas``  — the Pallas TPU kernels, in interpret mode on the CPU
     backend only. A call the kernel cannot serve (a ``kv_mask``, a
     ``q_offset``, an ``initial_state`` or a ragged chunk) raises rather
@@ -14,30 +24,129 @@ Models keep the (B, S, H, D) layout; this module adapts to kernel layouts.
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 import os
-from typing import Optional
+from typing import Callable, Iterator, List, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import splash_attention as _splash
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import ref as _ref
 from repro.kernels import ssd_scan as _ssd
+from repro.models import shardings as _sh
+
+# the splash kernel's tiles: sequence and head_dim in multiples of a lane row
+_LANES = 128
+
+_observers: List[Callable[[str, str], None]] = []
 
 
-def _impl(override: Optional[str]) -> str:
-    return override or os.environ.get("REPRO_KERNEL_IMPL", "blocked")
+def _impl(override: Optional[str]) -> Optional[str]:
+    return override or os.environ.get("REPRO_KERNEL_IMPL")
+
+
+def _backend() -> str:
+    return jax.default_backend()
 
 
 def _interpret() -> bool:
     """Interpret mode on the CPU backend, compiled kernels elsewhere."""
-    return jax.default_backend() == "cpu"
+    return _backend() == "cpu"
 
 
 def _unservable(kernel: str, why: str) -> NotImplementedError:
     return NotImplementedError(
         f"impl='pallas': the {kernel} kernel does not support {why}; "
         f"ask for impl='blocked'")
+
+
+@contextlib.contextmanager
+def observe_attention(fn: Callable[[str, str], None]) -> Iterator[None]:
+    """Call ``fn(path, reason)`` each time ``attention`` is traced inside
+    the block with the default dispatch or ``impl='blocked'|'splash'``:
+    path ``fused`` (reason ``""``) or ``blocked``, with the first rule of
+    the dispatch policy the call fails (``cross``, ``kv_mask``,
+    ``q_offset``, ``sliding_window``, ``lengths``, ``seq``, ``head_dim``,
+    ``mesh``, ``backend``) or ``impl`` where it was named."""
+    _observers.append(fn)
+    try:
+        yield
+    finally:
+        _observers.remove(fn)
+
+
+def _served(path: str, reason: str = "") -> None:
+    for fn in list(_observers):
+        fn(path, reason)
+
+
+def _fused_refusal(q, k, *, cross: bool = False, sliding_window: int = 0,
+                  q_offset=0, kv_mask=None) -> Optional[str]:
+    """The first rule of the dispatch policy that keeps this call off the
+    fused kernel, or None where the kernel serves it."""
+    mesh = _sh.get_mesh()
+    sq, sk = q.shape[1], k.shape[1]
+    rules = (
+        ("cross", cross),
+        ("kv_mask", kv_mask is not None),
+        ("q_offset", not (isinstance(q_offset, int) and q_offset == 0)),
+        ("sliding_window", bool(sliding_window)),
+        ("lengths", sq != sk),
+        ("seq", sq % _LANES != 0),
+        ("head_dim", q.shape[-1] % _LANES != 0),
+        ("mesh", mesh is not None and mesh.size > 1),
+        ("backend", _backend() != "tpu"),
+    )
+    return next((why for why, fails in rules if fails), None)
+
+
+def _block(seq: int) -> int:
+    """Splash tile for a sequence: the whole of one up to 256, else the
+    largest of 512, 256, 128 dividing it into at least two blocks, so a
+    causal mask has fully masked blocks to skip."""
+    if seq <= 2 * _LANES:
+        return seq
+    return next(b for b in (512, 256, _LANES) if seq % b == 0 and seq > b)
+
+
+@functools.lru_cache(maxsize=16)
+def _splash_kernel(seq: int, heads: int, causal: bool, interpret: bool):
+    """One splash kernel and its mask per static shape (built eagerly,
+    also when first asked for inside a trace)."""
+    b = _block(seq)
+    # with one KV block, dq comes out of the dk/dv kernel rounded once, as
+    # from the dq kernel; with more, that kernel would round a partial dq
+    # per KV block before their sum, so dq keeps its own kernel
+    one_kv_block = b == seq
+    dq_blocks = {} if one_kv_block else dict(block_q_dq=b, block_kv_dq=b)
+    sizes = _splash.BlockSizes(
+        block_q=b, block_kv=b, block_kv_compute=b,
+        block_q_dkv=b, block_kv_dkv=b, block_kv_dkv_compute=b,
+        use_fused_bwd_kernel=one_kv_block, **dq_blocks)
+    one = (_splash.CausalMask((seq, seq)) if causal
+           else _splash.FullMask((seq, seq)))
+    with jax.ensure_compile_time_eval():
+        return _splash.make_splash_mha(
+            _splash.MultiHeadMask([one] * heads), block_sizes=sizes,
+            head_shards=1, q_seq_shards=1, interpret=interpret)
+
+
+def _splash_attention(q, k, v, *, causal: bool) -> jnp.ndarray:
+    """(B, S, H, D) self-attention through the splash kernel, vmapped over
+    the batch; the 1/sqrt(D) scale is folded into q in f32 and rounded
+    once, as the matmul unit rounds the blocked path's f32 q."""
+    b, s, hq, d = q.shape
+    kernel = _splash_kernel(s, hq, causal, _interpret())
+    scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    heads_first = (0, 2, 1, 3)
+    out = jax.vmap(kernel)(qs.transpose(heads_first),
+                           k.transpose(heads_first),
+                           v.transpose(heads_first))
+    return out.transpose(heads_first)
 
 
 def attention(
@@ -49,11 +158,13 @@ def attention(
     sliding_window: int = 0,
     q_offset: int = 0,
     kv_mask: Optional[jnp.ndarray] = None,
+    cross: bool = False,
     impl: Optional[str] = None,
     block_q: int = 128,
     block_k: int = 512,
 ) -> jnp.ndarray:
-    """Multi-head (GQA) attention with causal / sliding-window masking."""
+    """Multi-head (GQA) attention with causal / sliding-window masking.
+    ``cross`` marks keys and values that come from another sequence."""
     impl = _impl(impl)
     if impl == "pallas":
         if kv_mask is not None or q_offset != 0:
@@ -69,6 +180,16 @@ def attention(
         return _ref.attention_naive(
             q, k, v, causal=causal, sliding_window=sliding_window,
             q_offset=q_offset, kv_mask=kv_mask)
+    why = _fused_refusal(q, k, cross=cross, sliding_window=sliding_window,
+                         q_offset=q_offset, kv_mask=kv_mask)
+    if impl == "splash" and why not in (None, "backend"):
+        raise NotImplementedError(
+            f"impl='splash': the splash kernel does not serve this call "
+            f"({why}); ask for impl='blocked'")
+    if impl == "splash" or (impl is None and why is None):
+        _served("fused")
+        return _splash_attention(q, k, v, causal=causal)
+    _served("blocked", why if impl is None else "impl")
     return _ref.attention_blocked(
         q, k, v, causal=causal, sliding_window=sliding_window,
         q_offset=q_offset, kv_mask=kv_mask, block_k=block_k)
@@ -119,7 +240,7 @@ def ssd(
     impl: Optional[str] = None,
 ):
     """Mamba2 SSD over a sequence; returns (y, final_state)."""
-    impl = _impl(impl)
+    impl = _impl(impl) or "blocked"
     s = x.shape[1]
     chunk = min(chunk, s)
     if impl == "pallas":

@@ -64,6 +64,7 @@ from repro.core.control import (ControlPlane, HyperTuneConfig, StepReport,
                                 policy_from_config)
 from repro.core.speed_model import SpeedModel, probe
 from repro.data.pipeline import HeteroPipeline
+from repro.kernels import ops
 from repro.models.model_factory import aux_inputs, build_model
 from repro.obs import (LOG, NULL_TRACER, ChromeTraceSink, EventLog,
                        MetricsRegistry, Tracer, gc_spans)
@@ -255,7 +256,9 @@ class HeteroTrainer:
         (``STEP_SPANS``, then ``retune``/``checkpoint``/``log`` when
         they happen), garbage collections are ``train/gc`` spans and
         backend compiles ``train/compile`` instants. With ``self.metrics``
-        set, the ``train.*`` counters count rows, retunes and compiles."""
+        set, the ``train.*`` counters count rows, retunes and compiles,
+        and ``kernels.attention.fused`` / ``.blocked`` the attention calls
+        traced while the step is built, by the path that serves each."""
         steps = steps if steps is not None else self.cfg.steps
         target = self.step + steps
         with contextlib.ExitStack() as observing:
@@ -266,6 +269,9 @@ class HeteroTrainer:
                 monitoring.register_event_duration_secs_listener(listener)
                 observing.callback(
                     monitoring.unregister_event_duration_listener, listener)
+            if self.metrics is not None:
+                observing.enter_context(
+                    ops.observe_attention(self._on_attention))
             while self.step < target:
                 self._step(report_fn, on_retune)
             if self.ckpt:
@@ -343,6 +349,13 @@ class HeteroTrainer:
             tr.instant("train", "compile", {"seconds": seconds})
         if mx is not None:
             mx.counter("train.compiles").inc()
+
+    def _on_attention(self, path: str, reason: str) -> None:
+        """The attention dispatch's hook, registered while :meth:`run`
+        counts."""
+        mx = self.metrics
+        if mx is not None:
+            mx.counter(f"kernels.attention.{path}").inc()
 
     @staticmethod
     def _healthy_reports(plan: BatchPlan) -> Dict[str, Dict[str, float]]:

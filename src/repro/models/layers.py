@@ -179,7 +179,7 @@ def attention_block(
         out = ops.attention(
             q, k, v, causal=causal and cross_x is None,
             sliding_window=cfg.sliding_window if cross_x is None else 0,
-            kv_mask=kv_mask)
+            kv_mask=kv_mask, cross=cross_x is not None)
         out = named(out, "attn_out")
         b, s = x.shape[:2]
         out = out.reshape(b, s, -1) @ p["wo"].astype(x.dtype)

@@ -127,6 +127,105 @@ class TestBlockedAttention:
         np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
+def _with_grads(impl, q, k, v, causal):
+    """The output and d<out, g>/d(q, k, v) for a fixed random cotangent g."""
+    g = rand(jax.random.PRNGKey(7), q.shape, jnp.float32)
+
+    def loss(q, k, v):
+        out = ops.attention(q, k, v, causal=causal, impl=impl)
+        return jnp.sum(out.astype(jnp.float32) * g)
+
+    return (ops.attention(q, k, v, causal=causal, impl=impl),) + \
+        jax.grad(loss, (0, 1, 2))(q, k, v)
+
+
+class TestSplashAttention:
+    """The fused splash kernel (interpret mode) vs the naive oracle, the
+    forward and the gradients with respect to q, k and v."""
+
+    @pytest.mark.parametrize("b,s,hq,hkv,causal,dtype", [
+        (2, 256, 2, 2, True, jnp.float32),      # causal MHA, one block
+        (1, 512, 8, 1, True, jnp.float32),      # GQA 8:1, masked block skipped
+        (1, 256, 2, 2, False, jnp.float32),     # non-causal
+        (1, 512, 4, 2, False, jnp.float32),     # non-causal, two blocks
+        (1, 512, 8, 1, True, jnp.bfloat16),     # bf16 GQA 8:1
+    ])
+    def test_forward_and_grads_match_naive(self, b, s, hq, hkv, causal,
+                                           dtype):
+        q, k, v = make_qkv(b, s, s, hq, hkv, 128, dtype)
+        got = _with_grads("splash", q, k, v, causal)
+        want = _with_grads("naive", q, k, v, causal)
+        # relative to each tensor's scale: the kernel rounds P and dS to
+        # the input dtype before their matmuls, as the MXU does on the chip
+        rel = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+        for name, a, w in zip(("out", "dq", "dk", "dv"), got, want):
+            assert a.dtype == w.dtype, name
+            a, w = np.float32(a), np.float32(w)
+            np.testing.assert_allclose(
+                a, w, rtol=rel, atol=rel * max(1.0, np.abs(w).max()),
+                err_msg=name)
+
+    def test_explicit_splash_refuses_what_it_cannot_serve(self):
+        q, k, v = make_qkv(1, 128, 128, 2, 2, 128, jnp.float32)
+        with pytest.raises(NotImplementedError, match="blocked"):
+            ops.attention(q, k, v, kv_mask=jnp.ones((1, 128), bool),
+                          impl="splash")
+
+
+class TestAttentionDispatch:
+    """The default dispatch takes the fused kernel only where the call
+    shows nothing it cannot serve, and says why it did not."""
+
+    @staticmethod
+    def paths(fn):
+        seen = []
+        with ops.observe_attention(lambda path, why: seen.append((path, why))):
+            jax.eval_shape(fn)
+        return seen
+
+    @pytest.mark.parametrize("why,sq,sk,d,kw", [
+        ("kv_mask", 128, 128, 128, dict(kv_mask=jnp.ones((1, 128), bool))),
+        ("q_offset", 128, 128, 128, dict(q_offset=128)),
+        ("sliding_window", 128, 128, 128, dict(sliding_window=64)),
+        ("seq", 96, 96, 128, {}),
+        ("cross", 128, 128, 128, dict(cross=True, causal=False)),
+        ("lengths", 128, 256, 128, dict(causal=False)),
+        ("head_dim", 128, 128, 64, {}),
+    ])
+    def test_falls_back_to_blocked_with_its_reason(self, monkeypatch, why,
+                                                   sq, sk, d, kw):
+        monkeypatch.setattr(ops, "_backend", lambda: "tpu")
+        q, k, v = make_qkv(1, sq, sk, 4, 2, d, jnp.float32)
+        assert self.paths(lambda: ops.attention(q, k, v, **kw)) == \
+            [("blocked", why)]
+
+    def test_a_multi_device_mesh_falls_back(self, monkeypatch):
+        monkeypatch.setattr(ops, "_backend", lambda: "tpu")
+        monkeypatch.setattr(ops._sh, "get_mesh",
+                            lambda: type("Mesh", (), {"size": 4})())
+        q, k, v = make_qkv(1, 128, 128, 4, 2, 128, jnp.float32)
+        assert self.paths(lambda: ops.attention(q, k, v)) == \
+            [("blocked", "mesh")]
+
+    def test_tpu_self_attention_takes_the_fused_kernel(self, monkeypatch):
+        monkeypatch.setattr(ops, "_backend", lambda: "tpu")
+        q, k, v = make_qkv(2, 256, 256, 8, 1, 128, jnp.bfloat16)
+        assert self.paths(lambda: ops.attention(q, k, v)) == [("fused", "")]
+
+    def test_cpu_default_is_the_blocked_path_unchanged(self):
+        q, k, v = make_qkv(2, 256, 256, 8, 1, 128, jnp.float32)
+        assert self.paths(lambda: ops.attention(q, k, v)) == \
+            [("blocked", "backend")]
+        got = ops.attention(q, k, v)
+        want = ops.attention(q, k, v, impl="blocked")
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def test_a_named_impl_is_recorded_as_asked(self):
+        q, k, v = make_qkv(1, 128, 128, 2, 2, 128, jnp.float32)
+        assert self.paths(lambda: ops.attention(q, k, v, impl="blocked")) \
+            == [("blocked", "impl")]
+
+
 class TestSSDSweep:
     """Mamba2 SSD: Pallas chunked kernel + jnp chunked form vs the
     sequential-recurrence oracle."""

@@ -3,9 +3,10 @@
 The TPU compiler refuses what interpret mode accepts (block shapes off
 the (8, 128) tiling, ops Mosaic cannot lower, programs larger than the
 chip's HBM). These cases compile the Pallas kernels at real widths with
-``interpret=False``, and chip_smoke.py's whole train step, for one chip
-of a ``v5e:2x2`` topology. Nothing runs: results are checked by
-tests/test_kernels.py, times only on the chip.
+``interpret=False``, and the whole train steps of chip_smoke.py and of
+the yi9b-2l benchmark cell, for one chip of a ``v5e:2x2`` topology.
+Nothing runs: results are checked by tests/test_kernels.py, times only
+on the chip.
 
 The topology is described inside a fixture, never at import: one process
 at a time may load the TPU library, and every test worker imports this
@@ -17,6 +18,7 @@ import dataclasses
 import importlib.util
 import os
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +28,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs.base import get_arch
 from repro.core import hetero_dp
 from repro.kernels import flash_attention as fa
+from repro.kernels import ops
 from repro.kernels import ssd_scan as ssd
 from repro.models.model_factory import build_model
 from repro.optim.optimizer import AdamW, OptConfig
@@ -120,3 +123,38 @@ def test_chip_smoke_step_fits_one_chip(one_chip):
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert total <= V5E_HBM - 2e9, f"{total / 1e9:.2f} GB"
+
+
+def test_yi9b_step_attention_lowers_to_the_splash_kernels(one_chip,
+                                                         monkeypatch):
+    """The yi9b-2l benchmark cell's train step (6 x 1024, 32 q / 4 kv
+    heads of 128, remat) with the dispatch seeing a TPU backend: the
+    attention scope lowers to the splash kernels' custom calls and no f32
+    (B, H, Sq, 512) score block of the blocked scan is left."""
+    monkeypatch.setattr(ops, "_backend", lambda: "tpu")
+    arch = dataclasses.replace(get_arch("yi-9b"), num_layers=2,
+                               vocab_size=8000)
+    model, opt = build_model(arch), AdamW(OptConfig())
+
+    def init():
+        params = model.init(jax.random.PRNGKey(0))
+        return params, opt.init(params)
+
+    state = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
+                         jax.eval_shape(init))
+    batch = {"tokens": _spec(one_chip, (6, 1024), jnp.int32),
+             "targets": _spec(one_chip, (6, 1024), jnp.int32),
+             "sample_mask": _spec(one_chip, (6,), jnp.float32)}
+    seen = []
+    with ops.observe_attention(lambda path, why: seen.append(path)):
+        lowered = jax.jit(hetero_dp.make_train_step(model, opt)).lower(
+            *state, batch)
+    assert seen and set(seen) == {"fused"}
+    hlo = lowered.compile().as_text()
+    # a custom call's text spans lines (its kernel metadata): match across
+    calls = re.findall(r"%(splash_mha_\w+?)\.\d+ = [^\n]*custom-call\("
+                       r".*?op_name=\"([^\"]*)\"", hlo, re.S)
+    kinds = {name.split("_")[2] for name, _ in calls}
+    assert kinds == {"fwd", "dq", "dkv"}, kinds
+    assert all("/attention/" in op for _, op in calls)
+    assert not re.search(r"f32\[6,32,1024,512\]", hlo)

@@ -149,6 +149,23 @@ def test_a_new_shape_is_a_compile_instant_and_counter():
     assert all(e.args["seconds"] > 0 for e in instants)
 
 
+def test_attention_paths_are_counted_while_the_step_is_built():
+    t = tiny_trainer()
+    t.metrics = mx = MetricsRegistry()
+
+    def paths():
+        snap = mx.snapshot()
+        return {k: snap.get(f"kernels.attention.{k}", {}).get("value", 0)
+                for k in ("fused", "blocked")}
+
+    t.run(1)
+    built = paths()
+    # the CPU backend keeps every call on the blocked path
+    assert built["blocked"] >= 1 and built["fused"] == 0
+    t.run(2)
+    assert paths() == built                 # no new trace, no new count
+
+
 def test_untraced_run_records_nothing():
     t = tiny_trainer()
     assert not t.tracer and t.metrics is None
