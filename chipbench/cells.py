@@ -3,7 +3,10 @@
 A cell is one entry of ``BENCHMARK.json``'s ``workloads``. Its parts are
 files of their own, found by the names the entry gives:
 
-  configs/<config>.json   the model configuration as it is run
+  configs/<config>.json   the model configuration as it is run; it names
+                          the modules of this directory that count a
+                          step's work (``work``) and follow it in plain
+                          float32 (``reference``)
   traffic/<traffic>.json  the traffic mix: groups, speed curve, windows
   limits/<cell>.json      the limits of the comparison that decides
                           ``correct``, with the readings they came from
@@ -50,10 +53,14 @@ def load_cell(name: str, bench: Optional[Dict[str, Any]] = None) -> Cell:
         known = ", ".join(w["name"] for w in bench["workloads"])
         raise KeyError(f"no workload {name!r} in BENCHMARK.json ({known})")
     config = next(c for c in bench["configs"] if c["name"] == entry["config"])
+    body = _read(ROOT / config["file"])
+    if "work" not in body:
+        raise ValueError(f"{config['file']} names no work module ('work'): "
+                         f"the FLOPs and bytes of its step are uncounted")
     return Cell(
         name=name,
         chips=int(entry["chips"]),
-        config=_read(ROOT / config["file"]),
+        config=body,
         traffic=_read(HERE / "traffic" / f"{entry['traffic']}.json"),
         limits=_read(HERE / "limits" / f"{name}.json"),
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],

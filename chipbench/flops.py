@@ -1,7 +1,10 @@
 """The work a training step of a dense decoder requires, from shapes alone.
 
-``m`` is a configuration file's ``model`` block. Counts are of the work
-the mathematics requires, whatever implements it:
+The work module of the dense configurations (their files' ``"work":
+"flops"``): ``train_flops_per_token`` and ``step_bytes`` are what the
+harness reads (``harness.Context``). ``m`` is a configuration file's
+``model`` block. Counts are of the work the mathematics requires,
+whatever implements it:
 
   * FLOPs: the matrix products of the forward pass (2 per multiply-add),
     attention under its causal mask (query i attends to keys 0..i), the
@@ -66,12 +69,3 @@ def step_bytes(m: Model) -> float:
     """Least HBM bytes one optimizer step moves, whatever the batch."""
     return float(param_count(m)) * (ADAMW_BYTES_PER_PARAM
                                     + WEIGHT_READ_BYTES_PER_PARAM)
-
-
-def least_step_seconds(m: Model, seq_len: int, live_tokens: float,
-                       peak: Dict[str, Any]) -> tuple:
-    """(seconds, bound): the larger of FLOPs over peak and bytes over HBM
-    bandwidth for one step on ``live_tokens``, and which of the two it is."""
-    t_flops = live_tokens * train_flops_per_token(m, seq_len) / peak["bf16_flops_per_s"]
-    t_bytes = step_bytes(m) / peak["hbm_bytes_per_s"]
-    return (t_flops, "compute") if t_flops >= t_bytes else (t_bytes, "memory")

@@ -29,7 +29,7 @@ import jax
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from chipbench import cells, compare, flops, trace_reduce, traffic, weights
+from chipbench import cells, compare, trace_reduce, traffic, weights
 
 PEAKS = cells.HERE / "peaks.json"
 SPAN = "chipbench."
@@ -81,27 +81,70 @@ def compile_clock() -> Dict[str, float]:
     return totals
 
 
-# the program's configuration fields each ``model`` key is held to
-_FIELDS = {"family": "family", "num_layers": "num_layers",
-           "d_model": "d_model", "num_heads": "num_heads",
-           "num_kv_heads": "num_kv_heads", "head_dim": "resolved_head_dim",
-           "d_ff": "d_ff", "vocab_size": "vocab_size",
-           "activation": "activation", "norm_eps": "norm_eps",
-           "rope_theta": "rope_theta", "tie_embeddings": "tie_embeddings",
-           "qkv_bias": "qkv_bias", "sliding_window": "sliding_window",
-           "compute_dtype": "dtype"}
+# ``model`` keys that name a program attribute of another name
+_ALIASES = {"head_dim": "resolved_head_dim", "compute_dtype": "dtype"}
+# ``model`` keys of the benchmark's own: the program is held to them in
+# ``set_up``
+_OWN = ("param_dtype", "init_std")
+# program fields that add a mechanism when set, as a non-None block does
+_SWITCHES = ("cross_attn_every", "hybrid_attn_every", "is_encoder_decoder")
+
+
+def _departures(obj, stated: Dict[str, Any], path: str = "") -> Dict[str, tuple]:
+    """{key: (program, file)} for each key ``stated`` gives that ``obj``
+    lacks or departs from, a nested object held field by field against
+    the dataclass the attribute holds, and for each mechanism ``obj``
+    has that ``stated`` does not state."""
+    top = not path
+    wrong: Dict[str, tuple] = {}
+    for key, want in stated.items():
+        if top and key in _OWN:
+            continue
+        attr = _ALIASES.get(key, key) if top else key
+        where = path + key
+        if not hasattr(obj, attr):
+            wrong[where] = ("no such field", want)
+            continue
+        have = getattr(obj, attr)
+        if isinstance(want, dict):
+            if dataclasses.is_dataclass(have):
+                wrong.update(_departures(have, want, where + "."))
+            else:
+                wrong[where] = (have, want)
+        elif have != (tuple(want) if isinstance(want, list) else want):
+            wrong[where] = (have, want)
+    for f in dataclasses.fields(obj):
+        have = getattr(obj, f.name)
+        mechanism = dataclasses.is_dataclass(have) or (
+            top and f.name in _SWITCHES and have)
+        if mechanism and f.name not in stated:
+            wrong[path + f.name] = (have, "not stated")
+    return wrong
+
+
+def _overridden(obj, overrides: Dict[str, Any]):
+    """``obj`` with ``overrides``; an object value replaces fields of
+    the block the attribute holds."""
+    changes = {}
+    for key, value in overrides.items():
+        if isinstance(value, dict):
+            block = getattr(obj, key, None)
+            if not dataclasses.is_dataclass(block):
+                raise ValueError(f"override {key!r}: the program holds "
+                                 f"{block!r}, no block to change")
+            value = _overridden(block, value)
+        changes[key] = value
+    return dataclasses.replace(obj, **changes)
 
 
 def make_arch(config: Dict[str, Any]):
     """The registry's architecture with the file's overrides, held to
-    every size the file states."""
+    every key of the file's ``model`` block and refused where it has a
+    mechanism the block does not state."""
     from repro.configs.base import get_arch
 
-    arch = dataclasses.replace(get_arch(config["arch"]), **config["overrides"])
-    wrong = {k: (getattr(arch, f), config["model"][k])
-             for k, f in _FIELDS.items() if getattr(arch, f) != config["model"][k]}
-    if arch.moe or arch.ssm or arch.cross_attn_every or arch.is_encoder_decoder:
-        wrong["mechanism"] = ("not a dense decoder", "dense decoder")
+    arch = _overridden(get_arch(config["arch"]), config["overrides"])
+    wrong = _departures(arch, config["model"])
     if wrong:
         raise ValueError(f"the program's {config['arch']} departs from "
                          f"{config['name']}: {wrong} (program, file)")
@@ -279,20 +322,55 @@ def check(cell: cells.Cell, p: Program) -> tuple:
 
 @dataclasses.dataclass
 class Context:
-    """What a metric reader reads (``metrics/<name>.py``)."""
+    """What a metric reader reads (``metrics/<name>.py``). ``work`` is
+    the module the configuration names under ``work``:
+    ``train_flops_per_token(model, seq_len)`` and ``step_bytes(model)``
+    count the work a step of this model requires."""
 
     steps: List[Step]
     window_s: float
     setup_s: float
     seq_len: int
     model: Dict[str, Any]
+    work: Any
     peak: Dict[str, Any]
     chips: int
+    memory_peak_bytes: int = 0
     trace: Optional[Dict[str, Any]] = None
+
+    def flops_per_token(self) -> float:
+        return self.work.train_flops_per_token(self.model, self.seq_len)
+
+    def least_step_seconds(self, live_tokens: float) -> tuple:
+        """(seconds, bound): the larger of FLOPs over peak and bytes over
+        HBM bandwidth for one step on ``live_tokens``, and which of the
+        two it is."""
+        t_flops = live_tokens * self.flops_per_token() / self.peak["bf16_flops_per_s"]
+        t_bytes = self.work.step_bytes(self.model) / self.peak["hbm_bytes_per_s"]
+        return (t_flops, "compute") if t_flops >= t_bytes else (t_bytes, "memory")
+
+
+def read_metrics(metrics: List[Dict[str, Any]], ctx: Context) -> Dict[str, Any]:
+    """{name: {value, unit}} of each metric whose reader finds something
+    to read."""
+    out = {}
+    for m in metrics:
+        v = cells.reader(m["name"])(ctx)
+        if v is not None:
+            out[m["name"]] = {"value": v, "unit": m["unit"]}
+    return out
 
 
 def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
+
+
+def step_hlo(p: Program) -> str:
+    """The optimized HLO text of the compiled step the window ran (from
+    the persistent cache)."""
+    t = p.trainer
+    batch = {k: jax.numpy.asarray(v) for k, v in p.batches[-1].items()}
+    return t.step_fn.lower(t.params, t.opt_state, batch).compile().as_text()
 
 
 def run(name: str, seed: int, seconds: float, trace: bool, t0: float,
@@ -318,6 +396,12 @@ def run(name: str, seed: int, seconds: float, trace: bool, t0: float,
 
     tdir = tempfile.mkdtemp(prefix="chipbench-trace-") if trace else None
     if trace:
+        # the program's own spans, annotated into the profiler's trace,
+        # and its counters, for the traced window only
+        from repro.obs import NULL_TRACER, MetricsRegistry, Tracer
+
+        p.trainer.tracer = Tracer(source="train", annotate=TraceAnnotation)
+        p.trainer.metrics = registry = MetricsRegistry()
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         opts.host_tracer_level = 1
@@ -329,6 +413,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, t0: float,
         clock["cache_hits"] - before["cache_hits"]
     if trace:
         jax.profiler.stop_trace()
+        p.trainer.tracer, p.trainer.metrics = NULL_TRACER, None
     programs = p.trainer.step_fn._cache_size()
     log(f"window {window_s:.3f} s: {len(steps)} steps; compiles in the "
         f"window {in_window}; step programs {programs}")
@@ -337,16 +422,22 @@ def run(name: str, seed: int, seconds: float, trace: bool, t0: float,
                            f"{programs} step program(s): the window must "
                            f"run one compiled step")
 
-    summary = None
-    if trace:
-        summary = trace_reduce.reduce(trace_reduce.find_xplane(tdir), SPAN)
-        shutil.rmtree(tdir, ignore_errors=True)
-        log(f"trace: busy {summary['busy_s']:.4f} s of {summary['window_s']:.4f} s"
-            f" over {summary['n_steps']} steps; idle by host span "
-            f"{summary['idle_by_label']}")
     stats = dev.memory_stats() or {}
     mem_peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                    for d in devices) if stats else 0
+    summary = None
+    if trace:
+        summary = trace_reduce.reduce(trace_reduce.find_xplane(tdir), SPAN,
+                                      step_hlo(p))
+        shutil.rmtree(tdir, ignore_errors=True)
+        summary["counters"] = {k: v["value"] for k, v in
+                               registry.snapshot().items() if "value" in v}
+        log(f"trace: busy {summary['busy_s']:.4f} s of {summary['window_s']:.4f} s"
+            f" over {summary['n_steps']} steps; idle by host span "
+            f"{summary['idle_by_label']}; by program span "
+            f"{summary['idle_by_span']}; ops named from the HLO "
+            f"{len(summary['op_names'])} of {len(summary['op_s'])}; "
+            f"counters {summary['counters']}")
 
     free_program_state(p)
     losses = [s.loss for s in steps]
@@ -355,12 +446,9 @@ def run(name: str, seed: int, seconds: float, trace: bool, t0: float,
     ok = ok and failed == 0
 
     ctx = Context(steps, window_s, setup_s, cell.traffic["seq_len"],
-                  cell.config["model"], peak, len(devices), summary)
-    metrics = {}
-    for m in (cell.per_layer if trace else cell.end_to_end):
-        v = cells.reader(m["name"])(ctx)
-        if v is not None:
-            metrics[m["name"]] = {"value": v, "unit": m["unit"]}
+                  cell.config["model"], cells.module(cell.config["work"]),
+                  peak, len(devices), mem_peak, summary)
+    metrics = read_metrics(cell.per_layer if trace else cell.end_to_end, ctx)
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()), "memory_peak_bytes": mem_peak}
     result = {"correct": bool(ok), "attempted": len(steps), "failed": failed,
@@ -370,8 +458,8 @@ def run(name: str, seed: int, seconds: float, trace: bool, t0: float,
         device["window_s"] = summary["window_s"]
         result["breakdown"] = {"device_ops": summary["device_ops"],
                                "idle_gaps": summary["idle_gaps"]}
-    log(f"flops per live token {flops.train_flops_per_token(ctx.model, ctx.seq_len):.6g}; "
-        f"least bytes per step {flops.step_bytes(ctx.model):.6g}")
+    log(f"flops per live token {ctx.flops_per_token():.6g}; least bytes per "
+        f"step {ctx.work.step_bytes(ctx.model):.6g} ({cell.config['work']})")
     log(f"correct {ok}: rows distinct {distinct_rows(p.batches)}, "
         f"non-finite window losses {failed}, worst at {where}")
     for k, c in checks.items():

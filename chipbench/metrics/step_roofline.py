@@ -1,7 +1,6 @@
 """The least time for a step's required work (the larger of FLOPs over
-peak and bytes over HBM bandwidth, ``flops.py``, live rows only) over the
-device-busy time per step, in %."""
-from chipbench import flops
+peak and bytes over HBM bandwidth, from the configuration's work module,
+live rows only) over the device-busy time per step, in %."""
 
 
 def read(ctx):
@@ -9,6 +8,5 @@ def read(ctx):
     if not t or t["busy_s"] <= 0:
         return None
     live_tokens = sum(s.live_rows for s in ctx.steps) * ctx.seq_len / len(ctx.steps)
-    least, _ = flops.least_step_seconds(ctx.model, ctx.seq_len, live_tokens,
-                                        ctx.peak)
+    least, _ = ctx.least_step_seconds(live_tokens)
     return 100.0 * least / (t["busy_s"] / t["n_steps"])

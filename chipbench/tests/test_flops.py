@@ -1,5 +1,6 @@
 """The FLOP and byte counts against the program's parameter count and a
-count by hand, and the peaks table."""
+count by hand, the readers that take them through a configuration's work
+module, and the peaks table."""
 import json
 
 import pytest
@@ -46,13 +47,56 @@ def test_param_count_matches_program(cfg):
     assert total == flops.param_count(m) + 2 * (vp - v) * e
 
 
+def _config(name):
+    return json.loads((cells.HERE / "configs" / f"{name}.json").read_text())
+
+
 def test_published_cells_per_token():
-    ds = json.loads((cells.HERE / "configs" / "ds7b-2l.json").read_text())["model"]
-    yi = json.loads((cells.HERE / "configs" / "yi9b-2l.json").read_text())["model"]
+    ds, yi = _config("ds7b-2l")["model"], _config("yi9b-2l")["model"]
     assert flops.param_count(ds) == 509_628_416
     assert flops.param_count(yi) == 411_586_560
     assert flops.train_flops_per_token(ds, 256) == pytest.approx(2.756e9, rel=1e-3)
     assert flops.train_flops_per_token(yi, 1024) == pytest.approx(2.323e9, rel=1e-3)
+    # exactly what the counts gave before configurations named their
+    # work module
+    assert flops.train_flops_per_token(ds, 256) == 2755706880.0
+    assert flops.train_flops_per_token(yi, 1024) == 2323169280.0
+    assert flops.step_bytes(ds) == 14269595648.0
+    assert flops.step_bytes(yi) == 11524423680.0
+
+
+@pytest.mark.parametrize("cfg", ["ds7b-2l", "yi9b-2l"])
+def test_dense_configurations_name_flops(cfg):
+    assert cells.module(_config(cfg)["work"]) is flops
+
+
+# (config, seq_len, capacity rows, live rows) -> mfu, step_mfu,
+# step_roofline on one synthetic window, as the readers read them before
+# they took the counts through the work module: bit for bit
+PINNED = [
+    (("ds7b-2l", 256, 12, 12),
+     (42.68383699224202, 42.39927807896041, 44.78796980171874)),
+    (("ds7b-2l", 256, 12, 6),
+     (21.34191849612101, 21.199639039480203, 22.39398490085937)),
+    (("yi9b-2l", 1024, 6, 6),
+     (71.96830662403707, 71.48851791321015, 75.51604004916564)),
+]
+
+
+@pytest.mark.parametrize("cell,values", PINNED, ids=lambda x: str(x))
+def test_readers_through_the_work_module_are_unchanged(cell, values):
+    name, seq, rows, live = cell
+    config = _config(name)
+    steps = [harness.Step(0.1, live, rows, 0.0, 0.0, 1.0)] * 370
+    ctx = harness.Context(steps, 37.25, 15.0, seq, config["model"],
+                          cells.module(config["work"]),
+                          harness.peaks_for("TPU v5 lite"), 1,
+                          trace={"busy_s": 35.5, "window_s": 37.5,
+                                 "n_steps": 370})
+    got = tuple(cells.reader(k)(ctx) for k in ("mfu", "step_mfu",
+                                               "step_roofline"))
+    assert got == values
+    assert ctx.least_step_seconds(live * seq)[1] == "compute"
 
 
 def test_unknown_device_kind_raises():

@@ -1,22 +1,35 @@
-"""Reduce a profiler trace (``.xplane.pb``) to the benchmark's numbers.
+"""Reduce a profiler trace (``.xplane.pb``) to what the metric readers read.
 
 Read with ``jax.profiler.ProfileData`` and nothing else:
 
-  window      from the start of the first to the end of the last host
-              span named ``<prefix>step`` (the benchmark's own
-              annotation around each timed call);
-  busy        per device plane (``/device:TPU:<n>``), the union of the
-              intervals of the operations on its ``XLA Ops`` line,
-              clipped to the window; averaged over the devices;
-  device_ops  the operations that took the most time, by self time (an
-              op nested in another, as a loop body's ops are in the
-              loop, is taken out of its parent's time), summed by the
-              HLO instruction's name;
-  idle_gaps   the longest stretches of the window in which the first
-              device ran nothing, each labelled by the innermost
-              benchmark span open on the host at its middle (``input``,
-              ``control``, ``step`` for the rest of a timed call, and
-              ``between`` outside any call).
+  window        from the start of the first to the end of the last host
+                span named ``<prefix>step`` (the benchmark's own
+                annotation around each timed call);
+  busy          per device plane (``/device:TPU:<n>``), the union of the
+                intervals of the operations on its ``XLA Ops`` line,
+                clipped to the window; averaged over the devices;
+  op_s          every operation's self time on the first device (an op
+                nested in another, as a loop body's ops are in the loop,
+                is taken out of its parent's time), summed by the HLO
+                instruction's name; ``device_ops`` are the longest;
+  op_names      each of those ops' ``op_name`` metadata, read from the
+                compiled step's optimized HLO text where it is given:
+                the name scopes it ran under (``attention``, ``mlp``,
+                ...), a custom call's kernel name among them;
+  span_s        seconds inside the window under each of the program's
+                own host spans (``train.<phase>``, annotated through the
+                program's tracer);
+  idle_gaps     the longest stretches of the window in which the first
+                device ran nothing, each labelled by the innermost
+                benchmark span open on the host at its middle (``input``,
+                ``control``, ``step`` for the rest of a timed call, and
+                ``between`` outside any call);
+  idle_by_span  the first device's idle time split, gap by gap, over the
+                innermost span open on the host through it: a program
+                span, ``step`` for the rest of a timed call, ``between``
+                outside one.
+
+``scope_ms`` and ``span_ms`` select from a reduction for the readers.
 """
 from __future__ import annotations
 
@@ -24,12 +37,14 @@ import bisect
 import collections
 import glob
 import os
-from typing import Dict, List, Tuple
+import re
+from typing import Dict, Iterable, List, Optional, Tuple
 
 Interval = Tuple[float, float]
 
 OPS_LINE = "XLA Ops"
 TOP = 10
+PROGRAM_PREFIX = "train."
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -94,9 +109,12 @@ def self_times(events, lo: float, hi: float) -> collections.Counter:
     return out
 
 
-def read(path: str, prefix: str) -> Tuple[Dict[str, List[Interval]],
-                                           Dict[str, List[Tuple[str, float, float]]]]:
-    """({label: host spans}, {device plane: [(op, start, end)]}), in ns."""
+def read(path: str, prefixes: Tuple[str, ...]
+         ) -> Tuple[Dict[str, List[Interval]],
+                    Dict[str, List[Tuple[str, float, float]]]]:
+    """({host span name: intervals}, {device plane: [(op, start, end)]}),
+    in ns, for the host spans whose names start with one of
+    ``prefixes``."""
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(path)
@@ -106,8 +124,8 @@ def read(path: str, prefix: str) -> Tuple[Dict[str, List[Interval]],
         if plane.name.startswith("/host:"):
             for line in plane.lines:
                 for name, s, e in _events(line):
-                    if name.startswith(prefix):
-                        spans[name[len(prefix):]].append((s, e))
+                    if name.startswith(prefixes):
+                        spans[name].append((s, e))
         elif plane.name.startswith("/device:") and ":TPU:" in plane.name \
                 and plane.name.split(":")[-1].isdigit():
             ops = [ln for ln in plane.lines if ln.name == OPS_LINE]
@@ -117,7 +135,7 @@ def read(path: str, prefix: str) -> Tuple[Dict[str, List[Interval]],
 
 
 class Timeline:
-    """The innermost benchmark span open at each moment."""
+    """The innermost span open at each moment."""
 
     def __init__(self, spans: Dict[str, List[Interval]]):
         marks = []
@@ -142,9 +160,80 @@ class Timeline:
         return self.labels[i] if i >= 0 else "between"
 
 
-def reduce(path: str, prefix: str) -> Dict:
-    spans, devices = read(path, prefix)
-    steps = sorted(spans.get("step", []))
+def split_gap(timeline: Timeline, start: float, end: float) -> Dict[str, float]:
+    """{label: the part of [start, end] during which it was the
+    innermost open span}."""
+    out: Dict[str, float] = collections.Counter()
+    i = bisect.bisect_right(timeline.starts, start) - 1
+    t = start
+    while t < end:
+        label = timeline.labels[i] if i >= 0 else "between"
+        nxt = timeline.starts[i + 1] if i + 1 < len(timeline.starts) else end
+        piece_end = min(end, max(nxt, t))
+        if piece_end > t:
+            out[label] += piece_end - t
+        t = piece_end
+        i += 1
+    return dict(out)
+
+
+def idle_by_span(idle: Iterable[Interval], timeline: Timeline) -> Dict[str, float]:
+    total: Dict[str, float] = collections.Counter()
+    for s, e in idle:
+        for label, d in split_gap(timeline, s, e).items():
+            total[label] += d
+    return dict(total)
+
+
+# an HLO instruction's first line; the lines up to the next such line,
+# or up to the end of its computation, belong to it (a Pallas custom
+# call's kernel metadata spans lines)
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r"\bmetadata=\{[^}]*?\bop_name=\"([^\"]*)\"")
+_WRAPPED = re.compile(r"^(?:[\w.]+\()+|\)+$")
+
+
+def _instructions(hlo_text: str) -> Iterable[Tuple[str, str]]:
+    """(name, text with its continuation lines joined) of every
+    instruction of every computation of an HLO module's text."""
+    name, parts = None, []
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m or line.strip() in ("", "}"):
+            if name is not None:
+                yield name, "".join(parts)
+            name, parts = (m.group(1), [line]) if m else (None, [])
+        elif name is not None:
+            parts.append(line)
+    if name is not None:
+        yield name, "".join(parts)
+
+
+def hlo_op_names(hlo_text: str) -> Dict[str, str]:
+    """{instruction name: its ``op_name`` metadata}, for every
+    instruction of every computation (loop bodies included) of an HLO
+    module's text, instructions that span lines included."""
+    out = {}
+    for name, text in _instructions(hlo_text):
+        m = _OP_NAME.search(text)
+        if m:
+            out[name] = m.group(1)
+    return out
+
+
+def scopes_of(name: str) -> set:
+    """The components of an ``op_name``, a component ``f(g(x))``
+    counting as ``x``: ``jit(step)/transpose(jvp(mlp))/dot_general`` is
+    in ``step``, ``mlp`` and ``dot_general``."""
+    return {_WRAPPED.sub("", part) for part in name.split("/")}
+
+
+def reduce(path: str, prefix: str, hlo_text: str = "") -> Dict:
+    """The window of a profiler trace (its ``<prefix>step`` spans),
+    with every op's self time named from ``hlo_text`` and the program's
+    own spans (``PROGRAM_PREFIX``)."""
+    spans, devices = read(path, (prefix, PROGRAM_PREFIX))
+    steps = sorted(spans.get(prefix + "step", []))
     if not steps:
         raise ValueError(f"no {prefix}step spans in {path}")
     lo, hi = steps[0][0], max(e for _, e in steps)
@@ -158,13 +247,17 @@ def reduce(path: str, prefix: str) -> Dict:
             op_time = self_times(events, lo, hi)
     busy = (sum(busy_per_device) / len(busy_per_device)
             if busy_per_device else 0.0)
-    idle = sorted(gaps(first_busy, lo, hi), key=lambda g: g[0] - g[1]) \
-        if devices else []
-    timeline = Timeline(spans)
-    labelled = [(timeline.at((s + e) / 2), e - s) for s, e in idle]
+    idle = gaps(first_busy, lo, hi) if devices else []
+    longest = sorted(idle, key=lambda g: g[0] - g[1])
+    bench = {k[len(prefix):]: v for k, v in spans.items() if k.startswith(prefix)}
+    program = {k: v for k, v in spans.items() if k.startswith(PROGRAM_PREFIX)}
+    timeline = Timeline(bench)
+    labelled = [(timeline.at((s + e) / 2), e - s) for s, e in longest]
     by_label = collections.Counter()
     for label, d in labelled:
         by_label[label] += d * 1e-9
+    by_span = idle_by_span(idle, Timeline(dict(program, step=steps)))
+    names = hlo_op_names(hlo_text)
     return {
         "busy_s": busy * 1e-9,
         "window_s": (hi - lo) * 1e-9,
@@ -173,4 +266,29 @@ def reduce(path: str, prefix: str) -> Dict:
         "device_ops": [[n, t * 1e-9] for n, t in op_time.most_common(TOP)],
         "idle_gaps": [[label, d * 1e-9] for label, d in labelled[:TOP]],
         "idle_by_label": dict(by_label),
+        "op_s": {n: t * 1e-9 for n, t in op_time.items()},
+        "op_names": {n: names[n] for n in op_time if n in names},
+        "span_s": {k: sum(e - s for s, e in clip(v, lo, hi)) * 1e-9
+                   for k, v in sorted(program.items())},
+        "idle_by_span": {k: v * 1e-9 for k, v in sorted(by_span.items())},
     }
+
+
+def scope_ms(trace: Optional[Dict], scope: str) -> Optional[float]:
+    """Device self ms per step of the ops run under ``scope`` (a
+    component of their ``op_name``); None where the trace names no op
+    or none ran under it."""
+    if not trace or not trace.get("op_names"):
+        return None
+    names = trace["op_names"]
+    times = [t for op, t in trace["op_s"].items()
+             if scope in scopes_of(names.get(op, ""))]
+    return 1e3 * sum(times) / trace["n_steps"] if times else None
+
+
+def span_ms(trace: Optional[Dict], span: str) -> Optional[float]:
+    """Host ms per step under the program's span ``span``; None where
+    the trace holds none."""
+    if not trace or span not in trace.get("span_s", {}):
+        return None
+    return 1e3 * trace["span_s"][span] / trace["n_steps"]
