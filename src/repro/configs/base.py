@@ -25,6 +25,47 @@ class MoEConfig:
     router_jitter: float = 0.0
     # aux load-balance loss weight (switch-transformer style)
     aux_loss_weight: float = 0.01
+    # "softmax": softmax top-k with capacity drops (models/moe.py);
+    # "noaux_tc": DeepSeek-V3's sigmoid scores, top-k of score + a routing
+    # bias kept by a sign rule, the chosen scores normalised and scaled by
+    # ``routed_scale``; dropless, over the experts held (models/moe.py)
+    router: str = "softmax"
+    shared_experts: int = 0       # SwiGLU of shared_experts * expert_d_ff
+    routed_scale: float = 1.0
+    seq_aux_weight: float = 0.0   # alpha of the sequence-wise balance loss
+    bias_update_speed: float = 0.0  # gamma of the routing bias's sign rule
+    # this device's share under expert parallelism: experts
+    # [held_offset, held_offset + experts_held) of num_experts (0 = all)
+    experts_held: int = 0
+    held_offset: int = 0
+    dense_prefix: int = 0         # leading layers with a dense d_ff MLP
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3): keys and values
+    decompressed from a normed latent, plus one rotary key shared by all
+    heads; q/k heads of nope + rope, v heads of their own width."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    def params(self, d_model: int, heads: int) -> int:
+        return (d_model * heads * self.qk_head_dim
+                + d_model * (self.kv_lora_rank + self.qk_rope_head_dim)
+                + self.kv_lora_rank
+                + self.kv_lora_rank * heads * (self.qk_nope_head_dim
+                                               + self.v_head_dim)
+                + heads * self.v_head_dim * d_model)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,6 +95,7 @@ class ArchConfig:
     sliding_window: int = 0       # 0 = full attention
     activation: str = "swiglu"    # swiglu | gelu
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
     # hybrid (zamba2): attention block shared across layers, applied every k
     hybrid_attn_every: int = 0    # 0 = no interleaved attention
@@ -102,7 +144,10 @@ class ArchConfig:
         else:
             # attention
             nq, nkv = self.num_heads, self.num_kv_heads
-            attn = E * nq * h + 2 * E * nkv * h + nq * h * E
+            if self.mla is not None:
+                attn = self.mla.params(E, nq)
+            else:
+                attn = E * nq * h + 2 * E * nkv * h + nq * h * E
             if self.qkv_bias:
                 attn += (nq + 2 * nkv) * h
             if self.family == "hybrid":
@@ -114,8 +159,13 @@ class ArchConfig:
             else:
                 per_layer = attn
             if self.moe is not None:
-                fe = self.moe.expert_d_ff
-                ff = self.moe.num_experts * (3 * E * fe) + E * self.moe.num_experts
+                fe, m = self.moe.expert_d_ff, self.moe
+                ff = m.held * (3 * E * fe) + E * m.num_experts
+                if m.router == "noaux_tc":
+                    ff += m.num_experts  # the routing bias
+                ff += 3 * E * fe * m.shared_experts
+                # the leading dense layers: their MLP in place of experts
+                p += m.dense_prefix * (3 * E * self.d_ff - ff)
             elif self.family == "hybrid":
                 ff = 0
             elif self.activation == "swiglu":
@@ -145,9 +195,13 @@ class ArchConfig:
         E = self.d_model
         fe = self.moe.expert_d_ff
         total = self.param_count()
-        all_experts = self.num_layers * self.moe.num_experts * 3 * E * fe
-        active = self.num_layers * self.moe.top_k * 3 * E * fe
-        return total - all_experts + active
+        layers = self.num_layers - self.moe.dense_prefix
+        held = layers * self.moe.held * 3 * E * fe
+        # a token's top_k experts, of which the held share lies here
+        active = layers * 3 * E * fe * min(
+            self.moe.top_k * self.moe.held / self.moe.num_experts,
+            self.moe.held)
+        return int(total - held + active)
 
 
 def _ssm_layer_params(cfg: ArchConfig) -> int:
@@ -194,7 +248,7 @@ _ARCHS: Dict[str, ArchConfig] = {}
 _ARCH_MODULES = [
     "zamba2_1p2b", "codeqwen1p5_7b", "yi_9b", "qwen1p5_4b", "deepseek_7b",
     "llama32_vision_11b", "mamba2_1p3b", "whisper_tiny", "mixtral_8x7b",
-    "moonshot_v1_16b_a3b",
+    "moonshot_v1_16b_a3b", "moonlight_16b_a3b",
 ]
 
 
@@ -237,9 +291,18 @@ def reduced_config(cfg: ArchConfig, **overrides) -> ArchConfig:
         dtype="float32",
     )
     if cfg.moe is not None:
-        changes["moe"] = MoEConfig(
-            num_experts=4, top_k=2, expert_d_ff=64,
-            capacity_factor=cfg.moe.capacity_factor)
+        # every mechanism kept (router kind, shared experts, the dense
+        # prefix, a held share where one is set), widths and counts cut
+        n = 8 if cfg.moe.router == "noaux_tc" else 4
+        held = min(cfg.moe.experts_held, n // 2)
+        changes["moe"] = dataclasses.replace(
+            cfg.moe, num_experts=n, top_k=2, expert_d_ff=64,
+            experts_held=held,
+            held_offset=min(cfg.moe.held_offset, n - held) if held else 0,
+            dense_prefix=min(cfg.moe.dense_prefix, 1))
+    if cfg.mla is not None:
+        changes["mla"] = MLAConfig(kv_lora_rank=32, qk_nope_head_dim=16,
+                                   qk_rope_head_dim=8, v_head_dim=16)
     if cfg.ssm is not None:
         changes["ssm"] = SSMConfig(state_dim=16, head_dim=16, chunk_size=16,
                                    conv_width=cfg.ssm.conv_width, expand=2)

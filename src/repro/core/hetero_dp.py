@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.core.allocator import BatchPlan, row_mask
 from repro.models import layers as L
+from repro.models import moe as M
 from repro.models import shardings as sh
 from repro.models.model_factory import Model
 
@@ -26,6 +27,18 @@ NEG_INF = -1e30
 
 # the jitted train step's name: its HLO module is ``jit_<STEP_NAME>``
 STEP_NAME = "hypertune_train_step"
+
+# per-step routing counts a step of a dropless expert stack returns:
+# token-slots its held experts computed (summed over layers), the largest
+# held expert's rows in one layer, and token-slots routed to a held expert
+# that no product computed (0 by construction)
+ROUTING_COUNTERS = ("moe.rows.held", "moe.rows.max_expert", "moe.rows.dropped")
+
+
+def _routes(model: Model) -> bool:
+    """Whether the model's expert layers keep a routing bias (their loads
+    leave the forward pass)."""
+    return model.cfg.moe is not None and model.cfg.moe.router == "noaux_tc"
 
 
 def cross_entropy(logits: jnp.ndarray, targets: jnp.ndarray,
@@ -83,31 +96,40 @@ def chunked_ce_sums(model: Model, params, hidden: jnp.ndarray,
 
 
 def loss_sums(model: Model, params, batch: Dict[str, Any],
-              remat=True, ce_chunk: int = 0
-              ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """(Σ ce·mask, Σ mask, aux) — the unnormalized pieces, so microbatch
-    accumulation can normalize by the GLOBAL token count exactly."""
+              remat=True, ce_chunk: int = 0) -> Tuple:
+    """(Σ ce·mask, Σ mask, aux, routing stats) — the unnormalized pieces,
+    so microbatch accumulation can normalize by the GLOBAL token count
+    exactly. The stats are {} unless the expert layers keep a routing
+    bias."""
     seq = batch["tokens"].shape[1]
     m = token_mask(batch, seq)
-    hidden, aux = model.forward(params, batch, remat=remat,
-                                return_hidden=True)
+    if _routes(model):
+        hidden, aux, routing = model.forward(
+            params, batch, remat=remat, return_hidden=True, with_stats=True)
+    else:
+        hidden, aux = model.forward(params, batch, remat=remat,
+                                    return_hidden=True)
+        routing = {}
     if ce_chunk:
         tot, cnt = chunked_ce_sums(model, params, hidden, batch["targets"],
                                    m, ce_chunk)
-        return tot, cnt, aux
+        return tot, cnt, aux, routing
     with jax.named_scope("loss_head"):
         logits = L.logits(params["embed"], model.cfg, hidden)
         ce = cross_entropy(logits, batch["targets"], model.cfg.vocab_size)
-    return (ce * m).sum(), m.sum(), aux
+    return (ce * m).sum(), m.sum(), aux, routing
 
 
 def masked_loss(model: Model, params, batch: Dict[str, Any],
                 remat=True, ce_chunk: int = 0
                 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    tot, cnt, aux = loss_sums(model, params, batch, remat=remat,
-                              ce_chunk=ce_chunk)
+    tot, cnt, aux, routing = loss_sums(model, params, batch, remat=remat,
+                                       ce_chunk=ce_chunk)
     loss = tot / jnp.maximum(cnt, 1.0)
-    return loss + aux, {"ce": loss, "aux": aux, "tokens": cnt}
+    metrics = {"ce": loss, "aux": aux, "tokens": cnt}
+    if routing:
+        metrics["routing"] = routing
+    return loss + aux, metrics
 
 
 def make_train_step(model: Model, optimizer, remat=True,
@@ -127,12 +149,16 @@ def make_train_step(model: Model, optimizer, remat=True,
             return masked_loss(model, p, batch, remat=remat,
                                ce_chunk=ce_chunk)
         (loss, metrics), grads = jax.value_and_grad(lf, has_aux=True)(params)
+        routing = metrics.pop("routing", None)
         if grad_dtype is not None:
             # narrow the cross-replica gradient all-reduce (§Perf lever);
             # the optimizer re-widens to f32 internally
             grads = jax.tree.map(lambda g: g.astype(grad_dtype), grads)
         params, opt_state = _apply_update(optimizer, grads, opt_state,
                                           params)
+        if routing:
+            params = _route_bias_step(model, params, routing)
+            metrics.update(_routing_counts(routing))
         gn = optimizer.last_grad_norm(opt_state)
         return params, opt_state, {"loss": loss, "grad_norm": gn, **metrics}
 
@@ -159,27 +185,31 @@ def make_train_step(model: Model, optimizer, remat=True,
 
         def body(gacc, mb_i):
             def lf(p):
-                tot, cnt, aux = loss_sums(model, p, mb_i, remat=remat,
-                                          ce_chunk=ce_chunk)
-                return tot / t_global + aux / m, (tot, cnt, aux)
-            (_, (tot, cnt, aux)), g = jax.value_and_grad(
-                lf, has_aux=True)(params)
+                tot, cnt, aux, routing = loss_sums(
+                    model, p, mb_i, remat=remat, ce_chunk=ce_chunk)
+                return tot / t_global + aux / m, (tot, cnt, aux, routing)
+            (_, out), g = jax.value_and_grad(lf, has_aux=True)(params)
             gacc = jax.tree.map(
                 lambda a, b: a + b.astype(jnp.float32), gacc, g)
-            return gacc, (tot, cnt, aux)
+            return gacc, out
 
         from repro.models.scan_util import layer_scan
         g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-        grads, (tots, cnts, auxs) = layer_scan(body, g0, mb)
+        grads, (tots, cnts, auxs, routing) = layer_scan(body, g0, mb)
         grads = jax.tree.map(lambda g, p: g.astype(p.dtype), grads, params)
         ce = tots.sum() / t_global
         aux = auxs.mean()
         params, opt_state = _apply_update(optimizer, grads, opt_state,
                                           params)
+        metrics = {"ce": ce, "aux": aux, "tokens": cnts.sum()}
+        if routing:
+            # loads and rows add over the microbatches
+            routing = jax.tree.map(lambda a: a.sum(axis=0), routing)
+            params = _route_bias_step(model, params, routing)
+            metrics.update(_routing_counts(routing))
         gn = optimizer.last_grad_norm(opt_state)
         return params, opt_state, {"loss": ce + aux, "grad_norm": gn,
-                                   "ce": ce, "aux": aux,
-                                   "tokens": cnts.sum()}
+                                   **metrics}
 
     return _named(accum_step)
 
@@ -191,6 +221,26 @@ def _apply_update(optimizer, grads, opt_state, params):
         updates, opt_state = optimizer.update(grads, opt_state, params)
         params = jax.tree.map(lambda p, u: p + u, params, updates)
     return params, opt_state
+
+
+def _route_bias_step(model: Model, params, routing):
+    """Each expert layer's routing bias after the step: DeepSeek-V3's sign
+    rule on the loads of the step's live tokens (``routing["load"]``,
+    layers x experts), under the ``moe`` / ``router`` name scopes."""
+    with jax.named_scope("moe"), jax.named_scope("router"):
+        mp = params["layers"]["moe"]
+        bias = M.update_route_bias(mp["route_bias"], routing["load"],
+                                   model.cfg.moe.bias_update_speed)
+    return {**params, "layers": {**params["layers"],
+                                 "moe": {**mp, "route_bias": bias}}}
+
+
+def _routing_counts(routing) -> Dict[str, jnp.ndarray]:
+    """The step's ``ROUTING_COUNTERS`` from its routing stats."""
+    rows = routing["rows"]
+    return dict(zip(ROUTING_COUNTERS,
+                    (jnp.sum(rows), jnp.max(rows),
+                     jnp.sum(routing["dropped"]))))
 
 
 def _named(step: Callable) -> Callable:

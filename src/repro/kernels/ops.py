@@ -6,9 +6,13 @@ Dispatch policy (``impl`` argument or ``REPRO_KERNEL_IMPL`` env):
     forward, dq and dk/dv kernels, grouped KV heads, fully masked causal
     blocks skipped) when everything the call shows allows it: the TPU
     backend, self-attention, no ``kv_mask``, ``q_offset`` 0, no sliding
-    window, ``Sq == Sk`` a multiple of 128, a head_dim that is a multiple
-    of 128, and no multi-device model mesh. Every other call, and
-    ``ssd``, takes ``blocked``. ``observe_attention`` reports each choice.
+    window, ``Sq == Sk`` a multiple of 128, query/key heads of whole or
+    one and a half lane rows (128, 192 for latent attention, 256, ...),
+    value heads of whole lane rows, and no multi-device model mesh. Every
+    other call, and ``ssd``, takes ``blocked``. ``observe_attention``
+    reports each choice. ``grouped_matmul`` takes JAX's megablox kernel
+    (``gmm``, with its backward) under the same backend and mesh rules
+    where every dimension is whole lane rows, else ``lax.ragged_dot``.
   * ``blocked`` — pure-jnp online-softmax / chunked-scan refs.
     Numerically identical to the Pallas kernels, lowers on any backend and
     under any SPMD sharding; this is what the dry-run and CPU training use.
@@ -32,6 +36,7 @@ from typing import Callable, Iterator, List, Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu import splash_attention as _splash
+from jax.experimental.pallas.ops.tpu.megablox import ops as _megablox
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import ref as _ref
@@ -83,7 +88,14 @@ def _served(path: str, reason: str = "") -> None:
         fn(path, reason)
 
 
-def _fused_refusal(q, k, *, cross: bool = False, sliding_window: int = 0,
+def _heads_untiled(d_qk: int, d_v: int) -> bool:
+    """Whether the splash tiles cannot hold these heads: query/key heads
+    of whole or half lane rows and at least one (latent attention's 192
+    passes, 64 does not), value heads of whole lane rows."""
+    return d_qk < _LANES or d_qk % (_LANES // 2) != 0 or d_v % _LANES != 0
+
+
+def _fused_refusal(q, k, v, *, cross: bool = False, sliding_window: int = 0,
                   q_offset=0, kv_mask=None) -> Optional[str]:
     """The first rule of the dispatch policy that keeps this call off the
     fused kernel, or None where the kernel serves it."""
@@ -96,7 +108,7 @@ def _fused_refusal(q, k, *, cross: bool = False, sliding_window: int = 0,
         ("sliding_window", bool(sliding_window)),
         ("lengths", sq != sk),
         ("seq", sq % _LANES != 0),
-        ("head_dim", q.shape[-1] % _LANES != 0),
+        ("head_dim", _heads_untiled(q.shape[-1], v.shape[-1])),
         ("mesh", mesh is not None and mesh.size > 1),
         ("backend", _backend() != "tpu"),
     )
@@ -112,8 +124,20 @@ def _block(seq: int) -> int:
     return next(b for b in (512, 256, _LANES) if seq % b == 0 and seq > b)
 
 
-@functools.lru_cache(maxsize=16)
 def _splash_kernel(seq: int, heads: int, causal: bool, interpret: bool):
+    """The cached splash kernel for a static shape, built again where its
+    mask arrays were deleted (a process that frees every live array
+    between two trainers, as the benchmark's calibration does)."""
+    kernel = _built_splash_kernel(seq, heads, causal, interpret)
+    if any(a.is_deleted() for a in jax.tree.leaves(kernel)):
+        _built_splash_kernel.cache_clear()
+        kernel = _built_splash_kernel(seq, heads, causal, interpret)
+    return kernel
+
+
+@functools.lru_cache(maxsize=16)
+def _built_splash_kernel(seq: int, heads: int, causal: bool,
+                         interpret: bool):
     """One splash kernel and its mask per static shape (built eagerly,
     also when first asked for inside a trace)."""
     b = _block(seq)
@@ -136,8 +160,9 @@ def _splash_kernel(seq: int, heads: int, causal: bool, interpret: bool):
 
 def _splash_attention(q, k, v, *, causal: bool) -> jnp.ndarray:
     """(B, S, H, D) self-attention through the splash kernel, vmapped over
-    the batch; the 1/sqrt(D) scale is folded into q in f32 and rounded
-    once, as the matmul unit rounds the blocked path's f32 q."""
+    the batch; the 1/sqrt(D) scale (D of q and k) is folded into q in f32
+    and rounded once, as the matmul unit rounds the blocked path's f32 q.
+    The output has v's head width."""
     b, s, hq, d = q.shape
     kernel = _splash_kernel(s, hq, causal, _interpret())
     scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
@@ -180,8 +205,9 @@ def attention(
         return _ref.attention_naive(
             q, k, v, causal=causal, sliding_window=sliding_window,
             q_offset=q_offset, kv_mask=kv_mask)
-    why = _fused_refusal(q, k, cross=cross, sliding_window=sliding_window,
-                         q_offset=q_offset, kv_mask=kv_mask)
+    why = _fused_refusal(q, k, v, cross=cross,
+                         sliding_window=sliding_window, q_offset=q_offset,
+                         kv_mask=kv_mask)
     if impl == "splash" and why not in (None, "backend"):
         raise NotImplementedError(
             f"impl='splash': the splash kernel does not serve this call "
@@ -193,6 +219,52 @@ def attention(
     return _ref.attention_blocked(
         q, k, v, causal=causal, sliding_window=sliding_window,
         q_offset=q_offset, kv_mask=kv_mask, block_k=block_k)
+
+
+def _gmm_tile(dim: int, largest: int) -> int:
+    """A megablox tile along one dimension: the whole of a dimension up to
+    ``largest`` (1408, an expert's width, is 11 lane rows: its only tiles
+    are 128 and itself), else the largest of 512, 256, 128 dividing it."""
+    if dim <= largest:
+        return dim
+    return next(t for t in (512, 256, _LANES) if dim % t == 0)
+
+
+def _gmm_tiles(m: int, k: int, n: int):
+    """megablox's tiling for one product (forward, or either product of
+    its backward), looked up per shape."""
+    return (_gmm_tile(m, 512), _gmm_tile(k, 1536), _gmm_tile(n, 1536))
+
+
+def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
+                   group_sizes: jnp.ndarray, *,
+                   impl: Optional[str] = None) -> jnp.ndarray:
+    """Rows ``[o_g, o_g + group_sizes[g])`` of ``lhs`` (M, K) times
+    ``rhs[g]`` (G, K, N), with ``o_g`` the sizes before ``g``; rows past
+    the last group come out zero and cost nothing on the fused path (the
+    work follows the rows the groups hold, not M). Outputs ``lhs``'s
+    dtype, accumulated in f32.
+
+    The fused path is megablox's ``gmm`` and its backward (``tgmm`` for
+    the weights), told of the rows past the groups as one more group that
+    no weight holds; ``impl='pallas'`` runs it in interpret mode on the
+    CPU backend, ``impl='blocked'`` takes ``lax.ragged_dot``."""
+    impl = _impl(impl)
+    m, k = lhs.shape
+    n = rhs.shape[-1]
+    mesh = _sh.get_mesh()
+    tiled = all(d % _LANES == 0 for d in (m, k, n))
+    fused = impl == "pallas" or (
+        impl is None and tiled and _backend() == "tpu"
+        and (mesh is None or mesh.size == 1))
+    if not fused:
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                                  preferred_element_type=jnp.float32
+                                  ).astype(lhs.dtype)
+    rest = m - jnp.sum(group_sizes)
+    sizes = jnp.concatenate([group_sizes, rest[None]]).astype(jnp.int32)
+    return _megablox.gmm(lhs, rhs, sizes, lhs.dtype, _gmm_tiles,
+                         jnp.zeros((), jnp.int32), None, False, _interpret())
 
 
 def decode_attention(

@@ -84,10 +84,12 @@ def attention_blocked(
     K/V stay in their storage dtype and are dynamic-sliced per block (no
     pre-stacked/pre-cast copy); GQA expansion happens per block. This is
     the algorithm the Pallas kernel implements tile-by-tile; it doubles as
-    the scalable CPU/dry-run attention path.
+    the scalable CPU/dry-run attention path. Values may be narrower than
+    queries and keys (latent attention: q/k 192, v 128).
     """
     orig_dtype = q.dtype
     b, sq, hq, d = q.shape
+    dv = v.shape[-1]
     sk, hkv = k.shape[1], k.shape[2]
     if sk % block_k:
         pad = block_k - sk % block_k
@@ -135,7 +137,7 @@ def attention_blocked(
 
     m0 = jnp.full((b, hq, sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, hq, sq), jnp.float32)
-    acc0 = jnp.zeros((b, hq, sq, d), jnp.float32)
+    acc0 = jnp.zeros((b, hq, sq, dv), jnp.float32)
     (m, l, acc), _ = layer_scan(body, (m0, l0, acc0), jnp.arange(nblocks))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     return out.transpose(0, 2, 1, 3).astype(orig_dtype)

@@ -117,8 +117,9 @@ def opt_shardings(opt_state_shape, param_shardings_tree, mesh: Mesh,
     state. XLA inserts the corresponding update-gather; measured in
     EXPERIMENTS.md §Perf (the HBM lever for the 47B-param mixtral).
     """
-    from repro.optim.optimizer import OptState
+    from repro.optim.optimizer import OptState, trained
     rep = NamedSharding(mesh, P())
+    param_shardings_tree = trained(param_shardings_tree)
 
     def z1(ns, leaf):
         spec = list(tuple(ns.spec)) + [None] * (len(leaf.shape)

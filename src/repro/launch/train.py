@@ -258,7 +258,9 @@ class HeteroTrainer:
         backend compiles ``train/compile`` instants. With ``self.metrics``
         set, the ``train.*`` counters count rows, retunes and compiles,
         and ``kernels.attention.fused`` / ``.blocked`` the attention calls
-        traced while the step is built, by the path that serves each."""
+        traced while the step is built, by the path that serves each; a
+        dropless expert stack adds its step's ``moe.rows.*`` counts
+        (``hetero_dp.ROUTING_COUNTERS``)."""
         steps = steps if steps is not None else self.cfg.steps
         target = self.step + steps
         with contextlib.ExitStack() as observing:
@@ -317,6 +319,9 @@ class HeteroTrainer:
         if mx is not None:
             mx.counter("train.rows.live").inc(plan.global_batch)
             mx.counter("train.rows.capacity").inc(plan.global_capacity)
+            for name in hetero_dp.ROUTING_COUNTERS:
+                if name in out:
+                    mx.counter(name).inc(int(out[name]))
             if event is not None:
                 mx.counter("train.retunes").inc()
 

@@ -3,6 +3,8 @@
 Model:
   init(key)                      -> params
   forward(params, batch, remat)  -> (logits, aux_loss)   # train / prefill
+                                    (+ routing stats, with_stats=True:
+                                     transformer family only)
   init_cache(params, B, max_len, dtype, aux) -> cache
   decode_step(params, cache, tokens, aux)    -> (logits, cache)
 """
@@ -53,9 +55,9 @@ def build_model(cfg: ArchConfig) -> Model:
     return Model(
         cfg=cfg,
         init=lambda key: mod.init(key, cfg),
-        forward=lambda params, batch, remat=True, return_hidden=False:
+        forward=lambda params, batch, remat=True, return_hidden=False, **kw:
             mod.forward(params, cfg, batch, remat=remat,
-                        return_hidden=return_hidden),
+                        return_hidden=return_hidden, **kw),
         init_cache=lambda params, b, mlen, dtype, aux=None: init_cache(
             params, cfg, b, mlen, dtype, aux),
         decode_step=lambda params, cache, tok, aux=None: decode(

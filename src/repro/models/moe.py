@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.kernels import ops
 from repro.models import shardings as sh
 
 Params = dict
@@ -114,3 +115,144 @@ def moe_block(p: Params, cfg: ArchConfig, x: jnp.ndarray
     y = combined.reshape(b, s, e).astype(dt)
     from repro.models.layers import named
     return named(sh.constrain_act(y, "res"), "ffn_out"), aux
+
+
+# ---------------------------------------------------------------------------
+# DeepSeek-V3 expert layer: dropless over the experts this device holds
+# ---------------------------------------------------------------------------
+#
+# The router scores every expert (sigmoid, f32); each token takes the top_k
+# of score + routing bias, weighted by its chosen scores (without the bias)
+# over their sum, times ``routed_scale``. The layer holds experts
+# [held_offset, held_offset + held) and computes their part of
+# sum_k w_k SwiGLU_k(x) for every token-slot routed to them: the slots are
+# sorted by held expert and run through grouped products whose work follows
+# the rows routed, so nothing is dropped whatever the imbalance. The shared
+# experts (one SwiGLU of shared_experts x expert_d_ff) see every token. On
+# one device the layer runs without the exchange expert parallelism adds.
+
+
+def init_held_experts(key, cfg: ArchConfig, out_scale: float = 1.0) -> Params:
+    m = cfg.moe
+    E, F, X, H = cfg.d_model, m.expert_d_ff, m.num_experts, m.held
+    ks = jax.random.split(key, 5)
+    s_in = 1.0 / (E ** 0.5)
+    s_out = out_scale / (F ** 0.5)
+    p = {
+        "router": jax.random.normal(ks[0], (E, X), jnp.float32) * s_in,
+        "route_bias": jnp.zeros((X,), jnp.float32),
+        "moe_gate": jax.random.normal(ks[1], (H, E, F), jnp.float32) * s_in,
+        "moe_up": jax.random.normal(ks[2], (H, E, F), jnp.float32) * s_in,
+        "moe_down": jax.random.normal(ks[3], (H, F, E), jnp.float32) * s_out,
+    }
+    if m.shared_experts:
+        from repro.models.layers import init_mlp
+        p["shared"] = init_mlp(ks[4], cfg, d_ff=m.shared_experts * F,
+                               out_scale=out_scale)
+    return p
+
+
+@jax.custom_vjp
+def _permute_rows(a, order, inverse):
+    """``a[order]`` for a permutation ``order`` whose inverse is
+    ``inverse``: its gradient is a gather too, not a scatter."""
+    return a[order]
+
+
+def _permute_fwd(a, order, inverse):
+    return a[order], (order, inverse)
+
+
+def _permute_bwd(res, g):
+    order, inverse = res
+    return g[inverse], None, None
+
+
+_permute_rows.defvjp(_permute_fwd, _permute_bwd)
+
+
+def route(p: Params, m, x: jnp.ndarray, live: jnp.ndarray):
+    """x (B, S, E); live (B,) row mask. Returns the chosen experts (B, S, k),
+    their weights (B, S, k, f32), the sequence-wise balance loss (alpha
+    times the mean over live rows of sum_i f_i P_i, DeepSeek-V3 eqs.
+    17-20, over all experts) and each expert's load: token-slots of live
+    rows that chose it (X,)."""
+    X, k = m.num_experts, m.top_k
+    s = x.shape[1]
+    scores = jax.nn.sigmoid(x.astype(jnp.float32) @ p["router"])
+    bias = jax.lax.stop_gradient(p["route_bias"])
+    _, top_i = jax.lax.top_k(scores + bias, k)
+    w = jnp.take_along_axis(scores, top_i, axis=-1)
+    w = w / jnp.sum(w, axis=-1, keepdims=True) * m.routed_scale
+    chosen = jnp.sum(jax.nn.one_hot(top_i, X, dtype=jnp.float32),
+                     axis=(1, 2))                                 # (B, X)
+    f = chosen * (X / (k * s))
+    share = jnp.mean(scores / jnp.sum(scores, axis=-1, keepdims=True),
+                     axis=1)                                      # (B, X)
+    per_row = jnp.sum(f * share, axis=-1)
+    aux = m.seq_aux_weight * jnp.sum(per_row * live) / jnp.maximum(
+        jnp.sum(live), 1.0)
+    load = jnp.sum(chosen * live[:, None], axis=0)
+    return top_i, w, aux, load
+
+
+def _held_experts(p: Params, m, x: jnp.ndarray, top_i, w):
+    """x (T, E); top_i, w (T, k). This device's experts' part of the
+    routed sum, (T, E) f32, and the rows each held expert computed (H,)."""
+    t, e = x.shape
+    k, H = top_i.shape[1], m.held
+    local = top_i.reshape(-1) - m.held_offset
+    held = (local >= 0) & (local < H)
+    group = jnp.where(held, local, H)                  # H: held elsewhere
+    order = jnp.argsort(group, stable=True)
+    inverse = jnp.argsort(order)
+    rows = jnp.sum(jax.nn.one_hot(group, H + 1, dtype=jnp.int32), axis=0)[:H]
+    slots = jnp.broadcast_to(x[:, None, :], (t, k, e)).reshape(t * k, e)
+    xs = _permute_rows(slots, order, inverse)
+    dt = x.dtype
+    gate = ops.grouped_matmul(xs, p["moe_gate"].astype(dt), rows)
+    up = ops.grouped_matmul(xs, p["moe_up"].astype(dt), rows)
+    out = ops.grouped_matmul(jax.nn.silu(gate) * up,
+                             p["moe_down"].astype(dt), rows)
+    ws = jnp.where(held, w.reshape(-1), 0.0)[order]
+    part = (out.astype(jnp.float32) * ws[:, None]).astype(dt)
+    y = _permute_rows(part, inverse, order).reshape(t, k, e)
+    return jnp.sum(y.astype(jnp.float32), axis=1), rows, jnp.sum(held)
+
+
+def held_experts_block(p: Params, cfg: ArchConfig, x: jnp.ndarray,
+                       live=None) -> Tuple[jnp.ndarray, jnp.ndarray, dict]:
+    """x (B, S, E), ``live`` (B,) the row mask (None: all rows) ->
+    (y, balance loss, stats): ``load`` (X,) the routing bias's input,
+    ``rows`` (H,) token-slots each held expert computed, ``dropped``
+    token-slots routed to a held expert that no product computed (0)."""
+    m = cfg.moe
+    b, s, e = x.shape
+    live = jnp.ones((b,), jnp.float32) if live is None else live
+    with jax.named_scope("moe"):
+        with jax.named_scope("router"):
+            top_i, w, aux, load = route(p, m, x, live.astype(jnp.float32))
+        with jax.named_scope("experts"):
+            y, rows, routed = _held_experts(
+                p, m, x.reshape(b * s, e), top_i.reshape(b * s, -1),
+                w.reshape(b * s, -1))
+            y = y.reshape(b, s, e)
+        if "shared" in p:
+            with jax.named_scope("shared_experts"):
+                sp = p["shared"]
+                dt = x.dtype
+                h = jax.nn.silu(x @ sp["w_gate"].astype(dt)) * (
+                    x @ sp["w_up"].astype(dt))
+                y = y + (h @ sp["w_down"].astype(dt)).astype(jnp.float32)
+        from repro.models.layers import named
+        stats = {"load": jax.lax.stop_gradient(load), "rows": rows,
+                 "dropped": routed - jnp.sum(rows)}
+        return named(y.astype(x.dtype), "ffn_out"), aux, stats
+
+
+def update_route_bias(bias: jnp.ndarray, load: jnp.ndarray,
+                      speed: float) -> jnp.ndarray:
+    """DeepSeek-V3's sign rule, per layer: b_i += speed * sign(mean load -
+    load_i); ``bias`` and ``load`` (..., X)."""
+    mean = jnp.mean(load, axis=-1, keepdims=True)
+    return bias + speed * jnp.sign(mean - load)

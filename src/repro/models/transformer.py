@@ -2,7 +2,10 @@
 
 Layers are weight-stacked and driven by ``lax.scan`` (small HLO, fast
 compiles at 30-48 layers). VLM cross-attention layers split the stack into
-segments: scan k dense layers, apply one cross block, repeat.
+segments: scan k dense layers, apply one cross block, repeat. An expert
+stack with leading dense layers (``moe.dense_prefix``) keeps those apart
+(``dense_layers``) and scans them first. Attention is latent (models/mla.py)
+where the config has an ``mla`` block.
 """
 from __future__ import annotations
 
@@ -14,6 +17,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.models.scan_util import layer_scan
 from repro.models import layers as L
+from repro.models import mla as MLA
 from repro.models import moe as M
 from repro.models import shardings as sh
 
@@ -28,37 +32,58 @@ def _slice(tree, a: int, b: int):
     return jax.tree.map(lambda x: x[a:b], tree)
 
 
-def _ffn(lp: Params, cfg: ArchConfig, x):
-    if cfg.moe is not None:
-        impl = sh.get_moe_impl()
-        if x.shape[1] > 1 and impl != "dense":
-            from repro.models import moe_ep
-            if impl == "ep_a2a" and moe_ep.ep_applicable(cfg, sh.get_mesh()):
-                return moe_ep.moe_block_ep(lp["moe"], cfg, x)
-            if impl == "fs" and moe_ep.fs_applicable(cfg, sh.get_mesh()):
-                return moe_ep.moe_block_fs(lp["moe"], cfg, x)
-        return M.moe_block(lp["moe"], cfg, x)
-    return L.mlp_block(lp["mlp"], cfg, x), jnp.zeros((), jnp.float32)
+def _ffn(lp: Params, cfg: ArchConfig, x, live=None):
+    """(y, aux loss, routing stats) of a layer's feed-forward block;
+    ``live`` is the batch's row mask."""
+    if "mlp" in lp:
+        return L.mlp_block(lp["mlp"], cfg, x), jnp.zeros((), jnp.float32), {}
+    if cfg.moe.router == "noaux_tc":
+        return M.held_experts_block(lp["moe"], cfg, x, live)
+    impl = sh.get_moe_impl()
+    if x.shape[1] > 1 and impl != "dense":
+        from repro.models import moe_ep
+        if impl == "ep_a2a" and moe_ep.ep_applicable(cfg, sh.get_mesh()):
+            return (*moe_ep.moe_block_ep(lp["moe"], cfg, x), {})
+        if impl == "fs" and moe_ep.fs_applicable(cfg, sh.get_mesh()):
+            return (*moe_ep.moe_block_fs(lp["moe"], cfg, x), {})
+    return (*M.moe_block(lp["moe"], cfg, x), {})
+
+
+def _attention(ap: Params, cfg: ArchConfig, x, positions):
+    if cfg.mla is not None:
+        return MLA.mla_block(ap, cfg, x, positions)
+    return L.attention_block(ap, cfg, x, positions=positions)
+
+
+def _dense_prefix(cfg: ArchConfig) -> int:
+    return cfg.moe.dense_prefix if cfg.moe is not None else 0
 
 
 def init(key, cfg: ArchConfig) -> Params:
     ks = jax.random.split(key, 4)
     out_scale = 1.0 / (2 * cfg.num_layers) ** 0.5
 
-    def one(k):
+    def one(k, dense):
         k1, k2 = jax.random.split(k)
         p = {"norm1": L.init_norm(cfg.d_model),
-             "attn": L.init_attention(k1, cfg, out_scale),
+             "attn": (MLA.init_mla(k1, cfg, out_scale) if cfg.mla is not None
+                      else L.init_attention(k1, cfg, out_scale)),
              "norm2": L.init_norm(cfg.d_model)}
-        if cfg.moe is not None:
-            p["moe"] = M.init_moe(k2, cfg, out_scale)
-        else:
+        if dense:
             p["mlp"] = L.init_mlp(k2, cfg, out_scale=out_scale)
+        elif cfg.moe.router == "noaux_tc":
+            p["moe"] = M.init_held_experts(k2, cfg, out_scale)
+        else:
+            p["moe"] = M.init_moe(k2, cfg, out_scale)
         return p
 
-    layers = _stack([one(k) for k in jax.random.split(ks[1], cfg.num_layers)])
+    keys = jax.random.split(ks[1], cfg.num_layers)
+    n_dense = _dense_prefix(cfg)
+    layers = _stack([one(k, cfg.moe is None) for k in keys[n_dense:]])
     params = {"embed": L.init_embedding(ks[0], cfg), "layers": layers,
               "final_norm": L.init_norm(cfg.d_model)}
+    if n_dense:
+        params["dense_layers"] = _stack([one(k, True) for k in keys[:n_dense]])
     if cfg.cross_attn_every:
         n_cross = cfg.num_layers // cfg.cross_attn_every
 
@@ -76,15 +101,16 @@ def init(key, cfg: ArchConfig) -> Params:
     return params
 
 
-def _layer_body(cfg: ArchConfig, positions):
+def _layer_body(cfg: ArchConfig, positions, live=None):
     def body(carry, lp):
         x, aux = carry
-        h = L.attention_block(lp["attn"], cfg,
-                              L.rmsnorm(x, lp["norm1"]["scale"], cfg.norm_eps),
-                              positions=positions)
+        h = _attention(lp["attn"], cfg,
+                       L.rmsnorm(x, lp["norm1"]["scale"], cfg.norm_eps),
+                       positions)
         x = x + h
-        h2, a = _ffn(lp, cfg, L.rmsnorm(x, lp["norm2"]["scale"], cfg.norm_eps))
-        return (x + h2, aux + a), None
+        h2, a, stats = _ffn(
+            lp, cfg, L.rmsnorm(x, lp["norm2"]["scale"], cfg.norm_eps), live)
+        return (x + h2, aux + a), stats
     return body
 
 
@@ -106,13 +132,19 @@ def _cross_block(cp: Params, cfg: ArchConfig, x, img, decode_cache=None):
 
 
 def forward(params: Params, cfg: ArchConfig, batch: Dict[str, Any],
-            remat: bool = True, return_hidden: bool = False
-            ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+            remat: bool = True, return_hidden: bool = False,
+            with_stats: bool = False):
+    """(logits or hidden, aux loss); with ``with_stats`` also the expert
+    layers' routing stats, stacked by layer ({} where none route)."""
     tokens = batch["tokens"]
     x = L.embed(params["embed"], cfg, tokens)
     positions = jnp.arange(tokens.shape[1])
-    body = L.maybe_checkpoint(_layer_body(cfg, positions), remat)
+    body = L.maybe_checkpoint(
+        _layer_body(cfg, positions, batch.get("sample_mask")), remat)
     aux0 = jnp.zeros((), jnp.float32)
+    stats: Dict[str, Any] = {}
+    if "dense_layers" in params:
+        (x, aux0), _ = layer_scan(body, (x, aux0), params["dense_layers"])
     if cfg.cross_attn_every:
         img = batch["img_embeds"].astype(x.dtype)
         seg = cfg.cross_attn_every
@@ -132,11 +164,10 @@ def forward(params: Params, cfg: ArchConfig, batch: Dict[str, Any],
                                            cfg.num_layers))
         x, aux = carry
     else:
-        (x, aux), _ = layer_scan(body, (x, aux0), params["layers"])
+        (x, aux), stats = layer_scan(body, (x, aux0), params["layers"])
     x = L.rmsnorm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    if return_hidden:
-        return x, aux
-    return L.logits(params["embed"], cfg, x), aux
+    out = x if return_hidden else L.logits(params["embed"], cfg, x)
+    return (out, aux, stats) if with_stats else (out, aux)
 
 
 # ---------------------------------------------------------------------------
@@ -148,11 +179,14 @@ def init_cache(params: Params, cfg: ArchConfig, batch: int, max_len: int,
                dtype, aux: Optional[Dict[str, Any]] = None) -> Params:
     smax = min(max_len, cfg.sliding_window) if cfg.sliding_window else max_len
     hkv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-    cache = {
-        "k": jnp.zeros((cfg.num_layers, batch, smax, hkv, hd), dtype),
-        "v": jnp.zeros((cfg.num_layers, batch, smax, hkv, hd), dtype),
-        "pos": jnp.zeros((batch,), jnp.int32),
-    }
+    if cfg.mla is not None:
+        cache = MLA.init_latent_cache(cfg, cfg.num_layers, batch, smax, dtype)
+    else:
+        cache = {
+            "k": jnp.zeros((cfg.num_layers, batch, smax, hkv, hd), dtype),
+            "v": jnp.zeros((cfg.num_layers, batch, smax, hkv, hd), dtype),
+        }
+    cache["pos"] = jnp.zeros((batch,), jnp.int32)
     if cfg.cross_attn_every:
         img = aux["img_embeds"].astype(dtype)
         ck, cv = jax.vmap(
@@ -164,17 +198,21 @@ def init_cache(params: Params, cfg: ArchConfig, batch: int, max_len: int,
 def decode_step(params: Params, cfg: ArchConfig, cache: Params,
                 tokens: jnp.ndarray, aux: Optional[Dict] = None
                 ) -> Tuple[jnp.ndarray, Params]:
-    """tokens (B,1) -> logits (B,1,V); advances the KV cache one position."""
+    """tokens (B,1) -> logits (B,1,V); advances the KV cache (the latent
+    cache under latent attention) one position."""
     x = L.embed(params["embed"], cfg, tokens)
     pos = cache["pos"]
+    ka, kb = ("c", "kr") if cfg.mla is not None else ("k", "v")
+    attend = MLA.mla_decode if cfg.mla is not None else L.attention_decode
 
     def body(x, scan_in):
         lp, kc, vc = scan_in
-        h, kc, vc = L.attention_decode(
+        h, kc, vc = attend(
             lp["attn"], cfg,
             L.rmsnorm(x, lp["norm1"]["scale"], cfg.norm_eps), kc, vc, pos)
         x = x + h
-        h2, _ = _ffn(lp, cfg, L.rmsnorm(x, lp["norm2"]["scale"], cfg.norm_eps))
+        h2, _, _ = _ffn(lp, cfg,
+                        L.rmsnorm(x, lp["norm2"]["scale"], cfg.norm_eps))
         return x + h2, (kc, vc)
 
     if cfg.cross_attn_every:
@@ -201,8 +239,18 @@ def decode_step(params: Params, cfg: ArchConfig, cache: Params,
         new_k = jnp.concatenate(ks, axis=0)
         new_v = jnp.concatenate(vs, axis=0)
     else:
-        x, (new_k, new_v) = layer_scan(
-            body, x, (params["layers"], cache["k"], cache["v"]))
+        n = _dense_prefix(cfg)
+        ks, vs = [], []
+        if n:
+            x, (kc, vc) = layer_scan(
+                body, x, (params["dense_layers"], cache[ka][:n],
+                          cache[kb][:n]))
+            ks.append(kc)
+            vs.append(vc)
+        x, (kc, vc) = layer_scan(
+            body, x, (params["layers"], cache[ka][n:], cache[kb][n:]))
+        new_k = jnp.concatenate(ks + [kc], axis=0) if n else kc
+        new_v = jnp.concatenate(vs + [vc], axis=0) if n else vc
     x = L.rmsnorm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    out = dict(cache, k=new_k, v=new_v, pos=pos + 1)
+    out = dict(cache, **{ka: new_k, kb: new_v}, pos=pos + 1)
     return L.logits(params["embed"], cfg, x), out

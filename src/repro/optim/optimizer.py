@@ -27,6 +27,29 @@ class OptConfig:
     compression: str = "none"           # none | bf16 | int8
 
 
+# parameter-tree keys whose leaves are state kept by a rule of their own
+# (the expert router's bias, models/moe.py), not trained: they get no
+# moments, no decay, no clipping share and a zero update here
+STATE_KEYS = frozenset({"route_bias"})
+
+
+def trained(tree):
+    """The parameter tree without its state leaves."""
+    if isinstance(tree, dict):
+        return {k: trained(v) for k, v in tree.items() if k not in STATE_KEYS}
+    return tree
+
+
+def _zeros_for_state(full, part):
+    """``part`` (a trained tree) completed to ``full``'s structure with
+    zeros at the state leaves."""
+    if isinstance(full, dict):
+        return {k: (jax.tree.map(jnp.zeros_like, v) if k in STATE_KEYS
+                    else _zeros_for_state(v, part[k]))
+                for k, v in full.items()}
+    return part
+
+
 class OptState(NamedTuple):
     step: jnp.ndarray
     mu: Any
@@ -55,12 +78,15 @@ class AdamW:
         self.cfg = cfg
 
     def init(self, params) -> OptState:
+        params = trained(params)
         zeros = lambda: jax.tree.map(jnp.zeros_like, params)
         ef = zeros() if self.cfg.compression != "none" else None
         return OptState(jnp.zeros((), jnp.int32), zeros(), zeros(),
                         jnp.zeros(()), ef)
 
     def update(self, grads, state: OptState, params):
+        full = params
+        grads, params = trained(grads), trained(params)
         cfg = self.cfg
         # gradient compression with error feedback (DCN-bound gradients)
         ef = state.ef
@@ -88,7 +114,7 @@ class AdamW:
             return u.astype(p.dtype)
 
         updates = jax.tree.map(upd, mu, nu, params)
-        return updates, OptState(step, mu, nu, gn, ef)
+        return _zeros_for_state(full, updates), OptState(step, mu, nu, gn, ef)
 
     @staticmethod
     def last_grad_norm(state: OptState) -> jnp.ndarray:

@@ -145,7 +145,8 @@ def test_moe_aux_loss_scales_with_imbalance():
 
 def test_param_count_analytic_matches_actual():
     """ArchConfig.param_count (used for MODEL_FLOPS) vs real init sizes."""
-    for arch in ("deepseek-7b", "mixtral-8x7b", "mamba2-1.3b", "zamba2-1.2b"):
+    for arch in ("deepseek-7b", "mixtral-8x7b", "mamba2-1.3b", "zamba2-1.2b",
+                 "moonlight-16b-a3b"):
         cfg = reduced_config(get_arch(arch))
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(0))
@@ -180,6 +181,9 @@ def test_full_configs_match_assignment():
         "moonshot-v1-16b-a3b": dict(num_layers=48, d_model=2048,
                                     num_heads=16, num_kv_heads=16,
                                     vocab_size=163840),
+        "moonlight-16b-a3b": dict(num_layers=27, d_model=2048, num_heads=16,
+                                  num_kv_heads=16, d_ff=11264,
+                                  vocab_size=163840, rope_theta=50000.0),
     }
     for arch, want in spec.items():
         cfg = get_arch(arch)
@@ -191,6 +195,16 @@ def test_full_configs_match_assignment():
     assert (m.num_experts, m.top_k, m.expert_d_ff) == (8, 2, 14336)
     m = get_arch("moonshot-v1-16b-a3b").moe
     assert (m.num_experts, m.top_k, m.expert_d_ff) == (64, 6, 1408)
+    ml = get_arch("moonlight-16b-a3b")
+    m = ml.moe
+    assert (m.num_experts, m.top_k, m.expert_d_ff, m.shared_experts,
+            m.dense_prefix, m.router, m.routed_scale) == (
+        64, 6, 1408, 2, 1, "noaux_tc", 2.446)
+    assert (ml.mla.kv_lora_rank, ml.mla.qk_nope_head_dim,
+            ml.mla.qk_rope_head_dim, ml.mla.v_head_dim) == (512, 128, 64, 128)
+    # 15.96B parameters in all, 2.9B active a token: the catalog's 16B-A3B
+    assert round(ml.param_count() / 1e9, 2) == 15.96
+    assert round(ml.active_param_count() / 1e9, 1) == 2.9
 
 
 def test_long_500k_applicability():

@@ -158,3 +158,42 @@ def test_yi9b_step_attention_lowers_to_the_splash_kernels(one_chip,
     assert kinds == {"fwd", "dq", "dkv"}, kinds
     assert all("/attention/" in op for _, op in calls)
     assert not re.search(r"f32\[6,32,1024,512\]", hlo)
+
+
+def test_moonlight_step_lowers_to_fused_kernels_and_fits(one_chip,
+                                                         monkeypatch):
+    """The moonlight-5l cell's train step (one 8192-token row; latent
+    attention at q/k 192, v 128; 8 held experts of 64) with the dispatch
+    seeing a TPU: every attention call takes the splash kernels, the held
+    experts' products megablox's ``gmm`` and ``tgmm``, and the step fits
+    one v5e's 15.75 GiB the compiler allows (15.20 GB)."""
+    monkeypatch.setattr(ops, "_backend", lambda: "tpu")
+    arch = dataclasses.replace(
+        get_arch("moonlight-16b-a3b"), num_layers=5, vocab_size=20480,
+        moe=dataclasses.replace(get_arch("moonlight-16b-a3b").moe,
+                                experts_held=8))
+    model, opt = build_model(arch), AdamW(OptConfig())
+
+    def init():
+        params = model.init(jax.random.PRNGKey(0))
+        return params, opt.init(params)
+
+    state = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
+                         jax.eval_shape(init))
+    batch = {"tokens": _spec(one_chip, (1, 8192), jnp.int32),
+             "targets": _spec(one_chip, (1, 8192), jnp.int32),
+             "sample_mask": _spec(one_chip, (1,), jnp.float32)}
+    seen = []
+    with ops.observe_attention(lambda path, why: seen.append(path)):
+        lowered = jax.jit(hetero_dp.make_train_step(model, opt)).lower(
+            *state, batch)
+    assert seen == ["fused", "fused"]
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    for kernel in ("splash_mha_fwd", "gmm", "tgmm"):
+        assert re.search(rf"%{kernel}\w*\.\d+ = [^\n]*custom-call\(", hlo), \
+            kernel
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert total <= 15.75 * 2**30, f"{total / 1e9:.2f} GB"
