@@ -30,9 +30,11 @@ STEP_NAME = "hypertune_train_step"
 
 # per-step routing counts a step of a dropless expert stack returns:
 # token-slots its held experts computed (summed over layers), the largest
-# held expert's rows in one layer, and token-slots routed to a held expert
-# that no product computed (0 by construction)
-ROUTING_COUNTERS = ("moe.rows.held", "moe.rows.max_expert", "moe.rows.dropped")
+# held expert's rows in one layer, token-slots routed to a held expert
+# that no product computed (0 by construction), and the layers whose held
+# slots overflowed their buffer and ran over every window of it
+ROUTING_COUNTERS = ("moe.rows.held", "moe.rows.max_expert", "moe.rows.dropped",
+                    "moe.rows.overflow")
 
 
 def _routes(model: Model) -> bool:
@@ -240,7 +242,8 @@ def _routing_counts(routing) -> Dict[str, jnp.ndarray]:
     rows = routing["rows"]
     return dict(zip(ROUTING_COUNTERS,
                     (jnp.sum(rows), jnp.max(rows),
-                     jnp.sum(routing["dropped"]))))
+                     jnp.sum(routing["dropped"]),
+                     jnp.sum(routing["overflow"]))))
 
 
 def _named(step: Callable) -> Callable:

@@ -221,6 +221,10 @@ def attention(
         q_offset=q_offset, kv_mask=kv_mask, block_k=block_k)
 
 
+# megablox's row tile for an operand of more rows than it
+GMM_ROW_TILE = 512
+
+
 def _gmm_tile(dim: int, largest: int) -> int:
     """A megablox tile along one dimension: the whole of a dimension up to
     ``largest`` (1408, an expert's width, is 11 lane rows: its only tiles
@@ -233,7 +237,8 @@ def _gmm_tile(dim: int, largest: int) -> int:
 def _gmm_tiles(m: int, k: int, n: int):
     """megablox's tiling for one product (forward, or either product of
     its backward), looked up per shape."""
-    return (_gmm_tile(m, 512), _gmm_tile(k, 1536), _gmm_tile(n, 1536))
+    return (_gmm_tile(m, GMM_ROW_TILE), _gmm_tile(k, 1536),
+            _gmm_tile(n, 1536))
 
 
 def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
@@ -241,9 +246,11 @@ def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
                    impl: Optional[str] = None) -> jnp.ndarray:
     """Rows ``[o_g, o_g + group_sizes[g])`` of ``lhs`` (M, K) times
     ``rhs[g]`` (G, K, N), with ``o_g`` the sizes before ``g``; rows past
-    the last group come out zero and cost nothing on the fused path (the
-    work follows the rows the groups hold, not M). Outputs ``lhs``'s
-    dtype, accumulated in f32.
+    the last group come out zero. On the fused path the products visit
+    only the tiles the groups hold, but megablox then zero-fills the
+    rows past them in a pass over all of M x N, so M costs memory
+    traffic whatever the groups hold. Outputs ``lhs``'s dtype,
+    accumulated in f32.
 
     The fused path is megablox's ``gmm`` and its backward (``tgmm`` for
     the weights), told of the rows past the groups as one more group that

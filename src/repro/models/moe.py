@@ -11,6 +11,7 @@ config/hillclimb option handled in shardings.py.
 """
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -126,8 +127,10 @@ def moe_block(p: Params, cfg: ArchConfig, x: jnp.ndarray
 # over their sum, times ``routed_scale``. The layer holds experts
 # [held_offset, held_offset + held) and computes their part of
 # sum_k w_k SwiGLU_k(x) for every token-slot routed to them: the slots are
-# sorted by held expert and run through grouped products whose work follows
-# the rows routed, so nothing is dropped whatever the imbalance. The shared
+# sorted by held expert into a buffer sized to twice the uniform held share
+# and run through grouped products whose work follows the rows routed; a
+# step that holds more runs the buffer over every window of sorted slots,
+# so nothing is dropped whatever the imbalance. The shared
 # experts (one SwiGLU of shared_experts x expert_d_ff) see every token. On
 # one device the layer runs without the exchange expert parallelism adds.
 
@@ -150,25 +153,6 @@ def init_held_experts(key, cfg: ArchConfig, out_scale: float = 1.0) -> Params:
         p["shared"] = init_mlp(ks[4], cfg, d_ff=m.shared_experts * F,
                                out_scale=out_scale)
     return p
-
-
-@jax.custom_vjp
-def _permute_rows(a, order, inverse):
-    """``a[order]`` for a permutation ``order`` whose inverse is
-    ``inverse``: its gradient is a gather too, not a scatter."""
-    return a[order]
-
-
-def _permute_fwd(a, order, inverse):
-    return a[order], (order, inverse)
-
-
-def _permute_bwd(res, g):
-    order, inverse = res
-    return g[inverse], None, None
-
-
-_permute_rows.defvjp(_permute_fwd, _permute_bwd)
 
 
 def route(p: Params, m, x: jnp.ndarray, live: jnp.ndarray):
@@ -196,28 +180,113 @@ def route(p: Params, m, x: jnp.ndarray, live: jnp.ndarray):
     return top_i, w, aux, load
 
 
+def _buffer_rows(t: int, k: int, held: int, experts: int) -> int:
+    """Rows of the held token-slots' buffer for ``t`` tokens of ``k``
+    slots: twice the uniform held share, ``2 t k held / experts``, rounded
+    up to the grouped product's row tile, and at most ``t min(k, held)``,
+    the most held slots ``t`` tokens can have."""
+    tile = ops.GMM_ROW_TILE
+    share = -(-2 * t * k * held // (experts * tile)) * tile
+    return min(share, t * min(k, held))
+
+
+def _token_sums(b, tok, t: int):
+    """(t, E) f32: row i sums the rows of ``b`` (R, E) whose token
+    ``tok`` is i, a scatter-add over the R rows (its gradient is the
+    gather ``g[tok]``)."""
+    return jnp.zeros((t, b.shape[1]), jnp.float32).at[tok].add(
+        b.astype(jnp.float32))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _token_rows(x, tok, t: int):
+    """``x[tok]`` for ``x`` of ``t`` rows: each buffer row's token, rows
+    of ``x`` repeating; its gradient sums each token's rows in f32."""
+    return x[tok]
+
+
+def _token_rows_fwd(x, tok, t):
+    return x[tok], tok
+
+
+def _token_rows_bwd(t, tok, g):
+    return _token_sums(g, tok, t).astype(g.dtype), None
+
+
+_token_rows.defvjp(_token_rows_fwd, _token_rows_bwd)
+
+
+def _window_rows(rows, lo: int, r: int):
+    """Of ``rows`` (H,) held slots per expert, laid out by expert from
+    sorted position 0, how many of each lie in positions [lo, lo + r)."""
+    end = jnp.cumsum(rows)
+    return jnp.clip(end, lo, lo + r) - jnp.clip(end - rows, lo, lo + r)
+
+
+def _experts_over(x, mats, rows, ws, kept):
+    """The held experts over a buffer of token-slots ``kept`` (R,), held
+    ones first by expert (``rows`` of them per expert; the rest come out
+    zero): x (T, E) gathered by token, the three grouped products over R
+    rows, each slot's part weighted by ``ws`` (T k,) in f32 and summed
+    into its token in f32. Returns (T, E) f32."""
+    t = x.shape[0]
+    tok = kept // (ws.shape[0] // t)
+    xs = _token_rows(x, tok, t)
+    gate_w, up_w, down_w = mats
+    gate = ops.grouped_matmul(xs, gate_w, rows)
+    up = ops.grouped_matmul(xs, up_w, rows)
+    out = ops.grouped_matmul(jax.nn.silu(gate) * up, down_w, rows)
+    part = (out.astype(jnp.float32) * ws[kept][:, None]).astype(x.dtype)
+    return _token_sums(part, tok, t)
+
+
 def _held_experts(p: Params, m, x: jnp.ndarray, top_i, w):
     """x (T, E); top_i, w (T, k). This device's experts' part of the
-    routed sum, (T, E) f32, and the rows each held expert computed (H,)."""
-    t, e = x.shape
+    routed sum, (T, E) f32, the rows each held expert computed (H,), the
+    token-slots routed to them, and whether those overflowed the buffer
+    of ``_buffer_rows`` (0 or 1).
+
+    The slots are sorted once, held ones first by expert, and the first
+    R run through ``_experts_over``. Where more than R are held, every
+    window of R sorted slots runs in turn, each rematerialised, so that
+    nothing is dropped and no residual outgrows a window."""
+    t = x.shape[0]
     k, H = top_i.shape[1], m.held
     local = top_i.reshape(-1) - m.held_offset
     held = (local >= 0) & (local < H)
     group = jnp.where(held, local, H)                  # H: held elsewhere
     order = jnp.argsort(group, stable=True)
-    inverse = jnp.argsort(order)
     rows = jnp.sum(jax.nn.one_hot(group, H + 1, dtype=jnp.int32), axis=0)[:H]
-    slots = jnp.broadcast_to(x[:, None, :], (t, k, e)).reshape(t * k, e)
-    xs = _permute_rows(slots, order, inverse)
-    dt = x.dtype
-    gate = ops.grouped_matmul(xs, p["moe_gate"].astype(dt), rows)
-    up = ops.grouped_matmul(xs, p["moe_up"].astype(dt), rows)
-    out = ops.grouped_matmul(jax.nn.silu(gate) * up,
-                             p["moe_down"].astype(dt), rows)
-    ws = jnp.where(held, w.reshape(-1), 0.0)[order]
-    part = (out.astype(jnp.float32) * ws[:, None]).astype(dt)
-    y = _permute_rows(part, inverse, order).reshape(t, k, e)
-    return jnp.sum(y.astype(jnp.float32), axis=1), rows, jnp.sum(held)
+    routed = jnp.sum(held)
+    ws = jnp.where(held, w.reshape(-1), 0.0)
+    mats = tuple(p[n].astype(x.dtype) for n in ("moe_gate", "moe_up",
+                                                "moe_down"))
+    r = _buffer_rows(t, k, H, m.num_experts)
+    n = -(-t * min(k, H) // r)                     # windows of every held slot
+
+    def first_window(x, mats, rows, ws, order):
+        return _experts_over(x, mats, rows, ws, order[:r])
+
+    if n == 1:
+        return (first_window(x, mats, rows, ws, order), rows, routed,
+                jnp.zeros((), jnp.int32))
+
+    def every_window(x, mats, rows, ws, order):
+        order = jnp.pad(order, (0, max(0, n * r - t * k)))[:n * r]
+        window = jax.checkpoint(_experts_over)
+
+        def add(y, i):
+            kept = jax.lax.dynamic_slice_in_dim(order, i * r, r)
+            return y + window(x, mats, _window_rows(rows, i * r, r), ws,
+                              kept), None
+
+        return jax.lax.scan(add, jnp.zeros(x.shape, jnp.float32),
+                            jnp.arange(n))[0]
+
+    overflow = routed > r
+    y = jax.lax.cond(overflow, every_window, first_window,
+                     x, mats, rows, ws, order)
+    return y, rows, routed, overflow.astype(jnp.int32)
 
 
 def held_experts_block(p: Params, cfg: ArchConfig, x: jnp.ndarray,
@@ -225,7 +294,9 @@ def held_experts_block(p: Params, cfg: ArchConfig, x: jnp.ndarray,
     """x (B, S, E), ``live`` (B,) the row mask (None: all rows) ->
     (y, balance loss, stats): ``load`` (X,) the routing bias's input,
     ``rows`` (H,) token-slots each held expert computed, ``dropped``
-    token-slots routed to a held expert that no product computed (0)."""
+    token-slots routed to a held expert that no product computed (0),
+    ``overflow`` 1 where the held slots overflowed the buffer and ran
+    over every window of it, else 0."""
     m = cfg.moe
     b, s, e = x.shape
     live = jnp.ones((b,), jnp.float32) if live is None else live
@@ -233,7 +304,7 @@ def held_experts_block(p: Params, cfg: ArchConfig, x: jnp.ndarray,
         with jax.named_scope("router"):
             top_i, w, aux, load = route(p, m, x, live.astype(jnp.float32))
         with jax.named_scope("experts"):
-            y, rows, routed = _held_experts(
+            y, rows, routed, overflow = _held_experts(
                 p, m, x.reshape(b * s, e), top_i.reshape(b * s, -1),
                 w.reshape(b * s, -1))
             y = y.reshape(b, s, e)
@@ -246,7 +317,7 @@ def held_experts_block(p: Params, cfg: ArchConfig, x: jnp.ndarray,
                 y = y + (h @ sp["w_down"].astype(dt)).astype(jnp.float32)
         from repro.models.layers import named
         stats = {"load": jax.lax.stop_gradient(load), "rows": rows,
-                 "dropped": routed - jnp.sum(rows)}
+                 "dropped": routed - jnp.sum(rows), "overflow": overflow}
         return named(y.astype(x.dtype), "ffn_out"), aux, stats
 
 

@@ -188,13 +188,97 @@ def test_held_shares_add_up_to_the_uncut_layer():
     assert held == 2 * 16 * 2      # every token-slot computed once
 
 
-def test_held_layer_matches_reference_and_drops_nothing_under_imbalance():
-    """A bias that sends every token to held expert 2 (and one other):
-    nothing is dropped, and the output is the reference's."""
-    cfg = tiny(experts_held=4, held_offset=0)
+def full_size_held_experts(p, m, x, top_i, w):
+    """The held experts at full size, the reference the bounded buffer
+    is held to: every token-slot broadcast to (T k, E), permuted by held
+    expert, the products over all T k rows, permuted back and summed over
+    each token's slots."""
+    t, e = x.shape
+    k, H = top_i.shape[1], m.held
+    local = top_i.reshape(-1) - m.held_offset
+    held = (local >= 0) & (local < H)
+    group = jnp.where(held, local, H)
+    order = jnp.argsort(group, stable=True)
+    inverse = jnp.argsort(order)
+    rows = jnp.sum(jax.nn.one_hot(group, H + 1, dtype=jnp.int32), axis=0)[:H]
+    xs = jnp.broadcast_to(x[:, None, :], (t, k, e)).reshape(t * k, e)[order]
+    dt = x.dtype
+    gate = ops.grouped_matmul(xs, p["moe_gate"].astype(dt), rows)
+    up = ops.grouped_matmul(xs, p["moe_up"].astype(dt), rows)
+    out = ops.grouped_matmul(jax.nn.silu(gate) * up,
+                             p["moe_down"].astype(dt), rows)
+    ws = jnp.where(held, w.reshape(-1), 0.0)[order]
+    part = (out.astype(jnp.float32) * ws[:, None]).astype(dt)
+    y = part[inverse].reshape(t, k, e)
+    return (jnp.sum(y.astype(jnp.float32), axis=1), rows, jnp.sum(held),
+            jnp.zeros((), jnp.int32))
+
+
+# 512 tokens of 2 slots over 2 held experts of 8: a buffer of 512 rows,
+# below the 1024 held slots 512 tokens can have
+BOUNDED = dict(experts_held=2, held_offset=2)
+TOKENS = 512
+
+
+@pytest.mark.parametrize("bias,overflow", [
+    (None, 0),                       # ~256 held slots: the buffer's path
+    ({2: 10.0, 3: 5.0}, 1),          # all 1024 held: every window in turn
+])
+def test_bounded_buffer_matches_the_full_size_path(monkeypatch, bias,
+                                                   overflow):
+    """The held experts over their bounded buffer (on the CPU the
+    products take ``ragged_dot``, the blocked path) against the
+    full-size path, output and the gradients of x, the three expert
+    weights and the router, in f32 at the highest precision."""
+    cfg = tiny(**BOUNDED)
+    m = cfg.moe
+    assert M._buffer_rows(TOKENS, m.top_k, m.held, m.num_experts) == 512
+    p = held_layer(jax.random.PRNGKey(13), cfg)
+    if bias:
+        p["route_bias"] = jnp.zeros((8,)).at[jnp.array(list(bias))].set(
+            jnp.array(list(bias.values())))
+    x = normal(jax.random.PRNGKey(14), (2, TOKENS // 2, cfg.d_model))
+    cot = normal(jax.random.PRNGKey(15), x.shape)
+    names = ("router", "moe_gate", "moe_up", "moe_down")
+
+    def run():
+        def loss(x, q):
+            y, aux, st = M.held_experts_block(dict(p, **q), cfg, x)
+            return jnp.sum(y * cot) + aux, st
+        with jax.default_matmul_precision("highest"):
+            (_, st), grads = jax.value_and_grad(loss, (0, 1), has_aux=True)(
+                x, {n: p[n] for n in names})
+        return st, grads
+
+    st, (gx, gp) = run()
+    assert int(st["overflow"]) == overflow
+    assert int(st["dropped"]) == 0
+    assert (int(st["rows"].sum()) > 512) == bool(overflow)
+    with jax.default_matmul_precision("highest"):
+        y, _, _ = M.held_experts_block(p, cfg, x)
+    monkeypatch.setattr(M, "_held_experts", full_size_held_experts)
+    want_st, (want_gx, want_gp) = run()
+    with jax.default_matmul_precision("highest"):
+        want, _, _ = M.held_experts_block(p, cfg, x)
+    np.testing.assert_array_equal(np.asarray(st["rows"]),
+                                  np.asarray(want_st["rows"]))
+    # f32 sums taken in another order: within 1e-6 of each array's largest
+    for name, a, b in [("y", y, want), ("x", gx, want_gx)] + [
+            (n, gp[n], want_gp[n]) for n in names]:
+        b = np.asarray(b)
+        np.testing.assert_allclose(np.asarray(a), b, rtol=1e-5,
+                                   atol=1e-6 * float(np.abs(b).max()),
+                                   err_msg=name)
+
+
+def check_imbalanced_layer(share, tokens, bias, rows, overflow):
+    """A bias that sends every token to held experts: nothing is
+    dropped, and the output is the reference's."""
+    cfg = tiny(**share)
     p = held_layer(jax.random.PRNGKey(7), cfg)
-    p["route_bias"] = jnp.zeros((8,)).at[2].set(10.0).at[6].set(5.0)
-    x = normal(jax.random.PRNGKey(8), (1, 32, cfg.d_model))
+    p["route_bias"] = jnp.zeros((8,)).at[jnp.array(list(bias))].set(
+        jnp.array(list(bias.values())))
+    x = normal(jax.random.PRNGKey(8), (1, tokens, cfg.d_model))
     m = model_block(cfg)
     with jax.default_matmul_precision("highest"):
         y, aux, st = M.held_experts_block(p, cfg, x)
@@ -202,10 +286,26 @@ def test_held_layer_matches_reference_and_drops_nothing_under_imbalance():
     np.testing.assert_allclose(np.asarray(y[0]), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
     assert int(st["dropped"]) == 0
-    assert st["rows"].tolist() == [0, 0, 32, 0]
+    assert int(st["overflow"]) == overflow
+    assert st["rows"].tolist() == rows
     np.testing.assert_allclose(float(aux), m["moe"]["seq_aux_weight"]
                                * float(bal), rtol=1e-5)
     np.testing.assert_array_equal(np.asarray(st["load"]), np.asarray(load))
+
+
+def test_held_layer_matches_reference_and_drops_nothing_under_imbalance():
+    """Every token to held expert 2 and one held elsewhere: 32 held slots
+    at most, so the buffer holds them all."""
+    check_imbalanced_layer(dict(experts_held=4, held_offset=0), 32,
+                           {2: 10.0, 6: 5.0}, [0, 0, 32, 0], 0)
+
+
+def test_held_layer_overflowing_its_buffer_matches_reference():
+    """Every slot of 512 tokens to the 2 held experts: 1024 held slots
+    over a buffer of 512 run every window of it, and still nothing is
+    dropped."""
+    check_imbalanced_layer(BOUNDED, TOKENS, {2: 10.0, 3: 5.0},
+                           [TOKENS, TOKENS], 1)
 
 
 def test_bias_sign_rule_and_balance_loss_skip_masked_rows():
@@ -252,10 +352,33 @@ def test_step_keeps_the_bias_out_of_adamw_and_counts_rows():
     assert np.all(np.isclose(step_size, 1e-3, rtol=1e-4)
                   | (step_size == 0.0)), moved
     assert int(out["moe.rows.dropped"]) == 0
+    assert int(out["moe.rows.overflow"]) == 0
     # every token-slot of both rows (masked ones are computed too) lands
     # on a held expert or elsewhere
     assert 0 < int(out["moe.rows.held"]) <= 2 * 16 * 2
     assert int(out["moe.rows.max_expert"]) <= int(out["moe.rows.held"])
+
+
+def test_trainer_counts_every_routing_counter():
+    """The trainer's counters take each of the step's ``moe.rows.*``
+    counts, ``moe.rows.overflow`` among them."""
+    from repro.core.allocator import solve
+    from repro.core.speed_model import SpeedModel
+    from repro.launch.train import HeteroTrainer, TrainerConfig
+    from repro.obs import MetricsRegistry
+
+    sm = SpeedModel(np.array([1.0, 2, 4, 8]), np.array([10.0, 18, 28, 30]))
+    t = HeteroTrainer(tiny(experts_held=4), solve({"g": (1, sm)}, 4096),
+                      TrainerConfig(seq_len=16, steps=1, log_every=0,
+                                    dataset_size=4096))
+    t.metrics = MetricsRegistry()
+    t.run(1)
+    names = [n for n in t.metrics.names() if n.startswith("moe.rows.")]
+    assert sorted(names) == sorted(hetero_dp.ROUTING_COUNTERS)
+    assert "moe.rows.overflow" in names
+    assert t.metrics.get("moe.rows.overflow").value == 0
+    assert t.metrics.get("moe.rows.dropped").value == 0
+    assert t.metrics.get("moe.rows.held").value > 0
 
 
 # ---------------------------------------------------------------------------
